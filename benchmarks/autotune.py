@@ -6,9 +6,10 @@ rung ladder's serving contracts.
         --out results/BENCH_autotune.json                         # CI
     PYTHONPATH=src python benchmarks/autotune.py                  # full
 
-Three stages:
+Three stages, each in its own subprocess (this orchestrator never
+touches JAX — a chip belongs to one process):
 
-  · **tune** (in-process): build one refine-codec index, run
+  · **tune**: build one refine-codec index, run
     ``repro.launch.tune.tune_index`` over the shared grid against the
     exact oracle, and evaluate three operating points on the held-out
     queries — the hand-picked default (``serve.DEFAULT_KC/K2``), the
@@ -111,7 +112,7 @@ def _equal(a, b) -> bool:
 
 
 # --------------------------------------------------------------------------
-# stage: tune (in-process)
+# stage: tune (subprocess)
 # --------------------------------------------------------------------------
 
 def run_tune(args) -> tuple:
@@ -310,9 +311,10 @@ def run_runtime(args) -> dict:
 def _spawn(stage: str, argv: list, devices: int = 1) -> dict:
     env = dict(os.environ)
     env["PYTHONPATH"] = f"src:{env.get('PYTHONPATH', '')}".rstrip(":")
-    env["XLA_FLAGS"] = (
-        env.get("XLA_FLAGS", "")
-        + f" --xla_force_host_platform_device_count={devices}").strip()
+    if env.get("JAX_PLATFORMS") == "cpu":    # emulated devices on the CPU
+        env["XLA_FLAGS"] = (
+            env.get("XLA_FLAGS", "")
+            + f" --xla_force_host_platform_device_count={devices}").strip()
     r = subprocess.run(
         [sys.executable, os.path.abspath(__file__), "--stage", stage,
          *argv], capture_output=True, text=True, env=env)
@@ -389,7 +391,7 @@ def main(argv=None) -> None:
     ap.add_argument("--smoke", action="store_true",
                     help="small corpus (CI scale)")
     ap.add_argument("--stage", default=None,
-                    choices=("variants", "runtime"),
+                    choices=("tune", "variants", "runtime"),
                     help="run ONE stage in-process (internal: the "
                          "default orchestrates the subprocess stages)")
     ap.add_argument("--top-r", type=int, default=100)
@@ -404,19 +406,20 @@ def main(argv=None) -> None:
     args = ap.parse_args(argv)
     _scale(args)
 
-    if args.stage == "variants":
+    if args.stage == "tune":
+        report = run_tune(args)[0]
+    elif args.stage == "variants":
         report = run_variants(args)
     elif args.stage == "runtime":
         if not args.tuned_json:
             sys.exit("--stage runtime needs --tuned-json")
         report = run_runtime(args)
     else:
-        tune_rep, tuned = run_tune(args)
-        from repro.core.exec import frontier
         sub = ["--top-r", str(args.top_r),
                "--max-batch", str(args.max_batch)]
         if args.smoke:
             sub.append("--smoke")
+        tune_rep = _spawn("tune", sub)
         report = {
             "bench": "autotune",
             "smoke": bool(args.smoke),
@@ -427,8 +430,7 @@ def main(argv=None) -> None:
             "variants": _spawn("variants", sub, devices=2),
             "runtime": _spawn(
                 "runtime",
-                sub + ["--tuned-json",
-                       json.dumps(frontier.to_json(tuned))]),
+                sub + ["--tuned-json", json.dumps(tune_rep["tuned"])]),
         }
 
     text = json.dumps(report, indent=2)

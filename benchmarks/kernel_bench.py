@@ -174,11 +174,13 @@ def run(args) -> dict:
             if not rep[kf]:
                 failures.append(f"{name}.{kf} is False")
 
+    from repro import kernels
+
     return {
         "bench": "kernels",
         "smoke": bool(args.smoke),
         "backend": jax.default_backend(),
-        "interpret_mode": jax.default_backend() != "tpu",
+        "interpret_mode": kernels.interpret_mode(),
         "pq_adc": adc,
         "sq8_dot": sq8,
         "assign_topk": topk,

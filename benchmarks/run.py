@@ -11,17 +11,19 @@ Every non-helper module in ``benchmarks/`` must have an entry in
 without one, so new benchmarks cannot be silently dropped from the
 suite (the mistake that previously left ``table3_codec`` and the
 streaming bench out of this driver).
+
+This process itself never touches JAX: a chip belongs to one process,
+so every benchmark, the in-process tables included, runs in a child.
 """
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import os
 import pathlib
 import subprocess
 import sys
-
-import jax
 
 #: benchmarks/ modules that are infrastructure, not benchmarks
 HELPER_MODULES = {"__init__", "common", "run", "check_regression"}
@@ -35,6 +37,27 @@ def discovered() -> list[str]:
                   if p.stem not in HELPER_MODULES)
 
 
+def _child_env() -> dict:
+    env = dict(os.environ)
+    env["PYTHONPATH"] = f"src:{env.get('PYTHONPATH', '')}".rstrip(":")
+    return env
+
+
+def _own_process(fn):
+    """Run ``fn`` (a function of this module) in a child process that
+    prints its CSV rows straight to this process's stdout."""
+    @functools.wraps(fn)
+    def spawn() -> None:
+        r = subprocess.run(
+            [sys.executable, "-c",
+             f"from benchmarks import run; run.{fn.__name__}.__wrapped__()"],
+            cwd=str(_DIR.parent), env=_child_env())
+        if r.returncode != 0:
+            sys.exit(f"{fn.__name__} failed (exit {r.returncode})")
+    return spawn
+
+
+@_own_process
 def _run_core_search() -> None:
     from benchmarks import common
     from repro.core import hybrid_index as hi
@@ -53,9 +76,12 @@ def _run_core_search() -> None:
     print(f"hi2_search_64q,{us64:.0f},oracle_path", flush=True)
 
 
+@_own_process
 def _run_kernels() -> None:
     # oracle-path timing only; the fused/unfused Pallas comparison is
     # benchmarks/kernel_bench.py (gated via results/BENCH_kernels.json)
+    import jax
+
     from benchmarks import common
     from repro.kernels.pq_adc import ref as adc_ref
 
@@ -68,6 +94,7 @@ def _run_kernels() -> None:
           flush=True)
 
 
+@_own_process
 def _table1() -> None:
     from benchmarks import table1_main
     for row in table1_main.run():
@@ -77,6 +104,7 @@ def _table1() -> None:
               f"index_mb={row['index_bytes']/2**20:.1f}", flush=True)
 
 
+@_own_process
 def _table2() -> None:
     from benchmarks import table2_robustness
     for row in table2_robustness.run():
@@ -84,6 +112,7 @@ def _table2() -> None:
               f"R@100={row['R100']:.4f}", flush=True)
 
 
+@_own_process
 def _table3() -> None:
     from benchmarks import table3_codec
     for row in table3_codec.run():
@@ -92,6 +121,7 @@ def _table3() -> None:
               f"index_mb={row['index_bytes']/2**20:.1f}", flush=True)
 
 
+@_own_process
 def _fig3() -> None:
     from benchmarks import fig3_tradeoff
     for name, pts in fig3_tradeoff.run().items():
@@ -99,6 +129,7 @@ def _fig3() -> None:
         print(f"fig3/{name},0,{pts_s}", flush=True)
 
 
+@_own_process
 def _fig4() -> None:
     from benchmarks import fig4_ablation
     for name, pts in fig4_ablation.run().items():
@@ -107,13 +138,11 @@ def _fig4() -> None:
 
 
 def _subprocess_json(module: str, extra_args: list[str]) -> dict:
-    """Run a benchmark that must own its process (device emulation via
-    XLA_FLAGS must precede jax import) and parse its JSON stdout."""
-    env = dict(os.environ)
-    env["PYTHONPATH"] = f"src:{env.get('PYTHONPATH', '')}".rstrip(":")
+    """Run a benchmark script in a child and parse its JSON stdout."""
     r = subprocess.run(
         [sys.executable, str(_DIR / f"{module}.py"), *extra_args],
-        capture_output=True, text=True, cwd=str(_DIR.parent), env=env)
+        capture_output=True, text=True, cwd=str(_DIR.parent),
+        env=_child_env())
     if r.returncode != 0:
         sys.exit(f"{module} failed:\n{r.stdout}\n{r.stderr}")
     return json.loads(r.stdout[r.stdout.index("{"):])

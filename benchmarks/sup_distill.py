@@ -5,9 +5,10 @@ the selector-quality evidence chain for HI²_sup.
         --out results/BENCH_sup.json                              # CI
     PYTHONPATH=src python benchmarks/sup_distill.py               # full
 
-Two stages:
+Two stages, each in its own subprocess (this orchestrator never touches
+JAX — a chip belongs to one process):
 
-  · **train + sweep** (in-process): build the HI²_unsup baseline, mine
+  · **train + sweep**: build the HI²_unsup baseline, mine
     its top-scoring non-relevant docs as hard negatives (union with the
     topic-matched pool), train the supervised selectors with in-batch
     negatives and the refine-stage KL (§15 recipe), assemble HI²_sup at
@@ -103,7 +104,7 @@ def _tree_equal(a, b) -> bool:
 
 
 # --------------------------------------------------------------------------
-# stage: train + sweep (in-process)
+# stage: train + sweep (subprocess)
 # --------------------------------------------------------------------------
 
 def run_train_sweep(args, ckpt_dir: str) -> dict:
@@ -302,9 +303,10 @@ def run_variants(args) -> dict:
 def _spawn(stage: str, argv: list, devices: int = 1) -> dict:
     env = dict(os.environ)
     env["PYTHONPATH"] = f"src:{env.get('PYTHONPATH', '')}".rstrip(":")
-    env["XLA_FLAGS"] = (
-        env.get("XLA_FLAGS", "")
-        + f" --xla_force_host_platform_device_count={devices}").strip()
+    if env.get("JAX_PLATFORMS") == "cpu":    # emulated devices on the CPU
+        env["XLA_FLAGS"] = (
+            env.get("XLA_FLAGS", "")
+            + f" --xla_force_host_platform_device_count={devices}").strip()
     r = subprocess.run(
         [sys.executable, os.path.abspath(__file__), "--stage", stage,
          *argv], capture_output=True, text=True, env=env)
@@ -356,13 +358,16 @@ def main(argv=None) -> None:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",
                     help="small corpus (CI scale)")
-    ap.add_argument("--stage", default=None, choices=("variants",),
-                    help="run ONE stage in-process (internal)")
+    ap.add_argument("--stage", default=None, choices=("train", "variants"),
+                    help="run ONE stage in-process (internal: the "
+                         "default orchestrates the subprocess stages)")
     ap.add_argument("--top-r", type=int, default=100)
     ap.add_argument("--steps", type=int, default=None,
                     help="override the training step count")
     ap.add_argument("--params-ckpt", default=None,
-                    help="trained-params checkpoint for --stage variants")
+                    help="trained-params checkpoint: the directory "
+                         "--stage train writes, the step --stage "
+                         "variants reads")
     ap.add_argument("--out", default=None,
                     help="write BENCH_sup.json here")
     ap.add_argument("--check", action="store_true",
@@ -371,26 +376,29 @@ def main(argv=None) -> None:
     args = ap.parse_args(argv)
     _scale(args)
 
-    if args.stage == "variants":
-        if not args.params_ckpt:
-            sys.exit("--stage variants needs --params-ckpt")
+    if args.stage in ("train", "variants") and not args.params_ckpt:
+        sys.exit(f"--stage {args.stage} needs --params-ckpt")
+    if args.stage == "train":
+        report = run_train_sweep(args, args.params_ckpt)
+    elif args.stage == "variants":
         report = run_variants(args)
     else:
+        sub = ["--top-r", str(args.top_r), "--steps", str(args.steps)]
+        if args.smoke:
+            sub.append("--smoke")
         with tempfile.TemporaryDirectory() as ckpt_dir:
-            sweep = run_train_sweep(args, ckpt_dir)
+            sweep = _spawn("train", sub + ["--params-ckpt", ckpt_dir])
             step_dir = os.path.join(
                 ckpt_dir, sorted(os.listdir(ckpt_dir))[-1])
-            sub = ["--top-r", str(args.top_r), "--steps", str(args.steps),
-                   "--params-ckpt", step_dir]
-            if args.smoke:
-                sub.append("--smoke")
             report = {
                 "bench": "sup_distill",
                 "smoke": bool(args.smoke),
                 "n_docs": args.docs,
                 "n_queries": args.queries,
                 **sweep,
-                "variants": _spawn("variants", sub, devices=2),
+                "variants": _spawn("variants",
+                                   sub + ["--params-ckpt", step_dir],
+                                   devices=2),
             }
 
     text = json.dumps(report, indent=2)

@@ -18,7 +18,7 @@ path used in training.
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -50,16 +50,47 @@ def init_kmeans(key: Array, doc_embeddings: Array, n_clusters: int,
     return selector, select_for_doc(selector, doc_embeddings)
 
 
+#: documents per row block of the indexing-side score plane: the
+#: (n_docs, L) plane never exists — 2^20 docs × 10k clusters would be
+#: 42 GB of f32 — only (DOC_BLOCK, L) tiles of it, one at a time
+DOC_BLOCK = 4096
+
+
 @jax.jit
 def scores(selector: ClusterSelector, x: Array) -> Array:
-    """⟨e_x, e_C⟩ for a batch: (B, h) -> (B, L)."""
-    return x.astype(jnp.float32) @ selector.embeddings.T
+    """⟨e_x, e_C⟩ for a batch: (B, h) -> (B, L), at full f32 precision
+    on every backend (the fused dispatch kernel's precision too)."""
+    return jnp.matmul(x.astype(jnp.float32), selector.embeddings.T,
+                      precision=jax.lax.Precision.HIGHEST)
 
 
-@jax.jit
+@functools.partial(jax.jit, static_argnames=("block",))
+def doc_scores(selector: ClusterSelector, doc_embeddings: Array,
+               doc_assign: Optional[Array] = None, *,
+               block: int = DOC_BLOCK) -> tuple[Array, Array]:
+    """Indexing side, one ``(block, L)`` tile of the score plane at a
+    time: each document's cluster — its argmax, or ``doc_assign`` when
+    given (the supervised path's frozen φ(D)) — and its score
+    ⟨e_D, e_C⟩ for that cluster.  Returns ((n,) i32, (n,) f32)."""
+    n = doc_embeddings.shape[0]
+    given = doc_assign is not None
+    assign = (jnp.asarray(doc_assign, jnp.int32) if given
+              else jnp.zeros((n,), jnp.int32))
+
+    def one_block(args):
+        xi, ai = args
+        s = scores(selector, xi)                             # (block, L)
+        if not given:
+            ai = jnp.argmax(s, axis=-1).astype(jnp.int32)
+        return ai, jnp.take_along_axis(s, ai[:, None], axis=-1)[:, 0]
+
+    return kmeans.map_blocks(one_block, (doc_embeddings, assign),
+                             min(block, n))
+
+
 def select_for_doc(selector: ClusterSelector, doc_embeddings: Array) -> Array:
     """Indexing side: each document goes to exactly one cluster."""
-    return jnp.argmax(scores(selector, doc_embeddings), axis=-1).astype(jnp.int32)
+    return doc_scores(selector, doc_embeddings)[0]
 
 
 @functools.partial(jax.jit, static_argnames=("k", "use_kernel"))

@@ -36,7 +36,7 @@ def search(query_embeddings: Array, doc_embeddings: Array, k: int,
     def body(carry, xs):
         best_s, best_i = carry
         blk, blk_idx = xs
-        s = q @ blk.T                                            # (B, block)
+        s = jnp.matmul(q, blk.T, precision=jax.lax.Precision.HIGHEST)
         ids = blk_idx * block + jnp.arange(block)
         valid = ids < n
         s = jnp.where(valid[None], s, -jnp.inf)
@@ -78,7 +78,8 @@ class FlatCodec(base.Codec):
 
         def score(ids: Array, live: Array = None) -> Array:
             rows = base.gather_rows(emb, ids)            # (B, C, h)
-            s = jnp.einsum("bh,bch->bc", q, rows)
+            s = jnp.einsum("bh,bch->bc", q, rows,
+                           precision=jax.lax.Precision.HIGHEST)
             return s if live is None else jnp.where(live, s, -jnp.inf)
 
         return score

@@ -26,6 +26,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import kmeans
 from repro.core.codecs import base
@@ -76,26 +77,44 @@ def train_pq(key: Array, x: Array, m: int, k: int = 256,
     return PQCodebook(codewords=codewords)
 
 
-@jax.jit
-def pq_encode(codebook: PQCodebook, x: Array) -> Array:
-    """Quantize embeddings to codes. (n, h) -> (n, m) int32 (values < k)."""
-    frags = split_fragments(x, codebook.m)  # (n, m, dsub)
+#: rows per block of :func:`pq_encode`'s (n, m, k) distance plane —
+#: 2^20 docs at m=96, k=256 would be 103 GB of f32 in one piece
+ENCODE_BLOCK = 4096
+
+
+@functools.partial(jax.jit, static_argnames=("block",))
+def pq_encode(codebook: PQCodebook, x: Array, *,
+              block: int = ENCODE_BLOCK) -> Array:
+    """Quantize embeddings to codes. (n, h) -> (n, m) int32 (values < k),
+    ``block`` rows at a time."""
     # distance argmin per subspace: argmax(<x, c> - ||c||²/2)
     c = codebook.codewords.astype(jnp.float32)  # (m, k, dsub)
     c_norm = 0.5 * jnp.sum(c * c, axis=-1)  # (m, k)
-    scores = jnp.einsum("nmd,mkd->nmk", frags.astype(jnp.float32), c) - c_norm
-    return jnp.argmax(scores, axis=-1).astype(jnp.int32)
+
+    def one_block(xi):
+        frags = split_fragments(xi, codebook.m)  # (block, m, dsub)
+        scores = jnp.einsum("nmd,mkd->nmk", frags.astype(jnp.float32),
+                            c) - c_norm
+        return jnp.argmax(scores, axis=-1).astype(jnp.int32)
+
+    return kmeans.map_blocks(one_block, x, min(block, x.shape[0]))
 
 
-@jax.jit
-def pq_decode(codebook: PQCodebook, codes: Array) -> Array:
-    """Reconstruct embeddings from codes. (n, m) -> (n, h)."""
-    gathered = jnp.take_along_axis(
-        codebook.codewords[None],            # (1, m, k, dsub)
-        codes[:, :, None, None],             # (n, m, 1, 1)
-        axis=2,
-    )[:, :, 0]                               # (n, m, dsub)
-    return gathered.reshape(codes.shape[0], -1)
+@functools.partial(jax.jit, static_argnames=("block",))
+def pq_decode(codebook: PQCodebook, codes: Array, *,
+              block: int = ENCODE_BLOCK) -> Array:
+    """Reconstruct embeddings from codes. (n, m) -> (n, h), ``block``
+    rows at a time (the (rows, m, dsub) gather pads dsub to a full lane
+    tile on TPU, so it must not span the corpus)."""
+    def one_block(ci):
+        gathered = jnp.take_along_axis(
+            codebook.codewords[None],            # (1, m, k, dsub)
+            ci[:, :, None, None],                # (block, m, 1, 1)
+            axis=2,
+        )[:, :, 0]                               # (block, m, dsub)
+        return gathered.reshape(ci.shape[0], -1)
+
+    return kmeans.map_blocks(one_block, codes, min(block, codes.shape[0]))
 
 
 @jax.jit
@@ -106,7 +125,8 @@ def adc_lut(codebook: PQCodebook, queries: Array) -> Array:
     """
     qf = split_fragments(queries, codebook.m)  # (B, m, dsub)
     return jnp.einsum("bmd,mkd->bmk", qf.astype(jnp.float32),
-                      codebook.codewords.astype(jnp.float32))
+                      codebook.codewords.astype(jnp.float32),
+                      precision=jax.lax.Precision.HIGHEST)
 
 
 @jax.jit
@@ -159,16 +179,37 @@ class OPQCodebook(NamedTuple):
         return self.codebook.m
 
 
+#: codebook-training points at most — Faiss's cap for OPQ and PQ
+#: training (``OPQMatrix::max_train_points`` = 256·256, and 256 points
+#: per centroid for k = 256): a larger corpus trains on a random subset
+#: of this size, and every document is still encoded
+MAX_TRAIN_POINTS = 256 * 256
+
+
+def train_sample(key: Array, x: Array,
+                 max_points: int = MAX_TRAIN_POINTS) -> Array:
+    """``x`` itself when it has at most ``max_points`` rows, else a
+    random subset of that many rows, in corpus order, seeded from
+    ``key`` and drawn on the host."""
+    n = x.shape[0]
+    if n <= max_points:
+        return x
+    seed = int(jax.random.randint(jax.random.fold_in(key, n), (), 0,
+                                  2 ** 31 - 1))
+    pick = np.random.default_rng(seed).choice(n, max_points, replace=False)
+    return x[jnp.asarray(np.sort(pick))]
+
+
 def train_opq(key: Array, x: Array, m: int, k: int = 256,
               n_outer: int = 4, n_kmeans_iters: int = 10) -> OPQCodebook:
     """Standard alternating scheme: PQ-train on rotated data (fix R, fit
     codebooks), then Procrustes-solve for R (fix codebooks: R = U Vᵀ
     from SVD of XᵀX̂, X̂ = decode(encode(XR))).  ``jnp.linalg.svd`` keeps
     everything in JAX; the rotation is h×h (≤ 1024²) so this is cheap
-    relative to the KMeans passes."""
+    relative to the KMeans passes.  Trains on :func:`train_sample`."""
     h = x.shape[-1]
     r = jnp.eye(h, dtype=jnp.float32)
-    x = x.astype(jnp.float32)
+    x = train_sample(key, x).astype(jnp.float32)
     cb = None
     for it in range(n_outer):
         key, sub = jax.random.split(key)
@@ -195,7 +236,9 @@ def opq_adc_lut(opq: OPQCodebook, queries: Array) -> Array:
 
     <x R, c> = <x, c Rᵀ> — rotating the query preserves Eq. 4 exactly.
     """
-    return adc_lut(opq.codebook, queries.astype(jnp.float32) @ opq.rotation)
+    return adc_lut(opq.codebook,
+                   jnp.matmul(queries.astype(jnp.float32), opq.rotation,
+                              precision=jax.lax.Precision.HIGHEST))
 
 
 def opq_reconstruction_mse(opq: OPQCodebook, x: Array) -> Array:
@@ -237,8 +280,8 @@ class PQCodec(base.Codec):
 
     def train(self, key: Array, embeddings: Array, *, pq_m: int = 8,
               pq_k: int = 256) -> PQCodebook:
-        return train_pq(key, embeddings.astype(jnp.float32),
-                        m=pq_m, k=pq_k)
+        return train_pq(key, train_sample(key, embeddings).astype(
+            jnp.float32), m=pq_m, k=pq_k)
 
     def encode(self, params: PQCodebook, embeddings: Array) -> dict:
         return {"codes": _pack_codes(pq_encode(params, embeddings),

@@ -24,6 +24,9 @@ import jax.numpy as jnp
 from repro.core.codecs import base
 
 Array = jax.Array
+# search-side contractions run at full f32 precision on every backend,
+# matching the fused kernel (DESIGN.md §11's tolerance is an f32 bound)
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 class SQ8Codec(base.Codec):
@@ -58,7 +61,7 @@ class SQ8Codec(base.Codec):
                     use_kernel: bool = False):
         q = queries.astype(jnp.float32)
         q_scaled = q * params["scale"]                   # (B, h)
-        bias = q @ params["lo"]                          # (B,)
+        bias = jnp.matmul(q, params["lo"], precision=HIGHEST)  # (B,)
         codes_plane = doc_planes["codes"]
 
         def score(ids: Array, live: Array = None) -> Array:
@@ -73,7 +76,7 @@ class SQ8Codec(base.Codec):
                 ) + bias[:, None]
             rows = base.gather_rows(codes_plane, ids)    # (B, C, h) u8
             s = (jnp.einsum("bh,bch->bc", q_scaled,
-                            rows.astype(jnp.float32))
+                            rows.astype(jnp.float32), precision=HIGHEST)
                  + bias[:, None])
             return s if live is None else jnp.where(live, s, -jnp.inf)
 

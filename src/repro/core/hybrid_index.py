@@ -163,11 +163,11 @@ def build(key: Array,
         doc_assign = cs_mod.select_for_doc(cluster_sel, doc_embeddings)
 
     if use_clusters:
-        assign_scores = np.asarray(
-            cs_mod.scores(cluster_sel, doc_embeddings)
-        )[np.arange(n_docs), np.asarray(doc_assign)]
+        _, assign_scores = cs_mod.doc_scores(cluster_sel, doc_embeddings,
+                                             doc_assign)
         cluster_lists = il.build(np.arange(n_docs), np.asarray(doc_assign),
-                                 assign_scores, n_lists=n_clusters,
+                                 np.asarray(assign_scores),
+                                 n_lists=n_clusters,
                                  capacity=cluster_capacity)
     else:
         cluster_lists = il.PaddedLists(

@@ -32,6 +32,19 @@ def _pad_to_multiple(x: Array, block: int, axis: int = 0,
     return jnp.pad(x, pad, constant_values=value), n
 
 
+def map_blocks(fn, xs, block: int):
+    """``fn`` over ``block``-row slices of the arrays ``xs`` (a pytree
+    of arrays with the same leading dim n), one slice at a time: the
+    last slice is zero-padded and the padding stripped from the
+    results, so only (block, ...) temporaries ever exist."""
+    n = jax.tree.leaves(xs)[0].shape[0]
+    blocked = jax.tree.map(
+        lambda a: _pad_to_multiple(a, block)[0].reshape(-1, block,
+                                                        *a.shape[1:]), xs)
+    return jax.tree.map(lambda a: a.reshape(-1, *a.shape[2:])[:n],
+                        jax.lax.map(fn, blocked))
+
+
 def assign_blocked(x: Array, centroids: Array, block: int = 4096) -> Array:
     """argmin_j ||x_i - c_j||² for every point, computed in MXU-friendly blocks.
 
@@ -39,15 +52,12 @@ def assign_blocked(x: Array, centroids: Array, block: int = 4096) -> Array:
     point so the argmin reduces to argmax(<x,c> - ||c||²/2).
     """
     c_norm = 0.5 * jnp.sum(centroids.astype(jnp.float32) ** 2, axis=-1)  # (L,)
-    xp, n = _pad_to_multiple(x, block)
-    xb = xp.reshape(-1, block, x.shape[-1])
 
     def one_block(xi):
         scores = xi.astype(jnp.float32) @ centroids.T.astype(jnp.float32) - c_norm
         return jnp.argmax(scores, axis=-1).astype(jnp.int32)
 
-    out = jax.lax.map(one_block, xb).reshape(-1)
-    return out[:n]
+    return map_blocks(one_block, x, block)
 
 
 def _update(x: Array, assign: Array, n_clusters: int) -> tuple[Array, Array]:

@@ -61,7 +61,6 @@ from repro.core import hybrid_index as hi
 from repro.core import sharded_index as shi
 from repro.core import term_selector as ts_mod
 from repro.core.inverted_lists import PAD_DOC, PaddedLists
-from repro.distributed import compat
 
 Array = jax.Array
 
@@ -435,11 +434,8 @@ class MutableHybridIndex:
                 f"delta segment full: {self._count}/{self.delta_capacity} "
                 f"slots used, {n_new} more requested — compact() first")
 
-        assign = np.asarray(cs_mod.select_for_doc(self.base.cluster_sel,
-                                                  jnp.asarray(emb)))
-        a_scores = np.asarray(cs_mod.scores(self.base.cluster_sel,
-                                            jnp.asarray(emb)))
-        a_scores = a_scores[np.arange(n_new), assign]
+        assign, a_scores = (np.asarray(v) for v in cs_mod.doc_scores(
+            self.base.cluster_sel, jnp.asarray(emb)))
         if self.selectors is not None:
             pos = self.selectors.position_scores(jnp.asarray(tokens))
         else:
@@ -779,11 +775,11 @@ def make_mutable_search_step(mesh, axis_name: str, codec: str, n_base: int,
         if filtered:
             in_specs.append(qspec)
             args.append(ns_filter)
-        mapped = compat.shard_map(
+        mapped = jax.shard_map(
             body, mesh=mesh,
             in_specs=tuple(in_specs),
             out_specs=(qspec, qspec, P(batch_axis)),
-            check=False)  # outputs replicated by construction (§6 merge)
+            check_vma=False)  # outputs replicated by construction (§6 merge)
         return mapped(*args)
 
     return run

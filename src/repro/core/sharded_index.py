@@ -341,11 +341,11 @@ def make_search_step(mesh: Mesh, axis_name: str, codec: str, per: int,
         if filtered:
             in_specs.append(qspec)       # bitmap rides with the queries
             args.append(ns_filter)
-        mapped = compat.shard_map(
+        mapped = jax.shard_map(
             body, mesh=mesh,
             in_specs=tuple(in_specs),
             out_specs=(qspec, qspec, P(batch_axis)),
-            check=False)  # outputs are replicated over the shard axis by
+            check_vma=False)  # outputs are replicated over the shard axis by
         #                   construction (merge ends in identical
         #                   all-gathered data on every shard)
         return mapped(*args)

@@ -145,6 +145,103 @@ def generate(seed: int = 0, *, n_docs: int = 20000, n_queries: int = 1000,
     return corpus
 
 
+def generate_device(seed: int = 0, *, n_docs: int, n_queries: int,
+                    hidden: int, vocab_size: int, n_topics: int = 128,
+                    doc_len: int = 64, query_len: int = 8,
+                    p_hard: float = 0.35, sigma_doc: float = 0.35,
+                    sigma_idio: float = 0.15, sigma_easy: float = 0.12,
+                    sigma_hard: float = 0.22, hard_topic_mix: float = 0.18,
+                    p_lexical: float = 0.75, topical_terms: int = 40,
+                    salient_per_doc: int = 3) -> Corpus:
+    """The generative model of :func:`generate`, sampled with
+    ``jax.random`` on the default device in float32.
+
+    For corpora at deployment scale (10⁶ docs × 768 dims), where the
+    float64 host generator would need minutes and tens of GB of host
+    memory.  Same parameters and semantics (``sigma_idio`` is the scale
+    :func:`generate` fixes at 0.15); a different random stream,
+    so the corpus is not the one :func:`generate` gives for the same
+    seed.  Every field of the returned :class:`Corpus` is a device
+    array; encoder B is not made.
+    """
+    import jax
+    import jax.numpy as jnp
+
+    keys = iter(jax.random.split(jax.random.key(seed), 20))
+    cdf = jnp.asarray(np.cumsum(_zipf_probs(vocab_size)), jnp.float32)
+
+    def normal(shape):
+        return jax.random.normal(next(keys), shape, jnp.float32)
+
+    def uniform(shape):
+        return jax.random.uniform(next(keys), shape)
+
+    def randint(shape, lo, hi):
+        return jax.random.randint(next(keys), shape, lo, hi, jnp.int32)
+
+    def zipf(shape):
+        return jnp.minimum(jnp.searchsorted(cdf, uniform(shape)),
+                           vocab_size - 1).astype(jnp.int32)
+
+    def normalize(x):
+        return x / jnp.maximum(jnp.linalg.norm(x, axis=-1, keepdims=True),
+                               1e-9)
+
+    def pick(table, rows, n_cols, width):
+        cols = randint((rows.shape[0], n_cols), 0, width)
+        return jnp.take_along_axis(table[rows], cols, axis=1)
+
+    # --- topics -------------------------------------------------------------
+    centers = normalize(normal((n_topics, hidden)))
+    topic_terms = randint((n_topics, topical_terms), vocab_size // 16,
+                          vocab_size // 2)
+
+    # --- documents ----------------------------------------------------------
+    doc_topic = randint((n_docs,), 0, n_topics)
+
+    @jax.jit
+    def doc_emb_of(k_doc, k_idio):    # one program: no (n, h) temporaries
+        shape = (n_docs, hidden)
+        return normalize(
+            centers[doc_topic]
+            + jax.random.normal(k_doc, shape, jnp.float32) * sigma_doc
+            + jax.random.normal(k_idio, shape, jnp.float32) * sigma_idio)
+
+    doc_emb = doc_emb_of(next(keys), next(keys))
+    n_top = doc_len // 3
+    salient = randint((n_docs, salient_per_doc), vocab_size // 2,
+                      vocab_size)
+    doc_tokens = jnp.concatenate(
+        [zipf((n_docs, doc_len - n_top - salient_per_doc)),
+         pick(topic_terms, doc_topic, n_top, topical_terms), salient], 1)
+    doc_tokens = jnp.take_along_axis(
+        doc_tokens, jnp.argsort(uniform(doc_tokens.shape), axis=1), axis=1)
+
+    # --- queries ------------------------------------------------------------
+    qrels = randint((n_queries,), 0, n_docs)
+    is_hard = uniform((n_queries,)) < p_hard
+    pos_emb = doc_emb[qrels]
+    hard_emb = normalize(
+        (1 - hard_topic_mix) * pos_emb
+        + hard_topic_mix * centers[randint((n_queries,), 0, n_topics)]
+        + normal((n_queries, hidden)) * sigma_hard)
+    easy_emb = normalize(pos_emb + normal((n_queries, hidden)) * sigma_easy)
+    query_emb = jnp.where(is_hard[:, None], hard_emb, easy_emb)
+
+    n_sal_q = min(2, salient_per_doc)
+    has_lex = uniform((n_queries, 1)) < p_lexical
+    q_sal = jnp.where(has_lex, salient[qrels][:, :n_sal_q],
+                      zipf((n_queries, n_sal_q)))
+    n_top_q = (query_len - n_sal_q) // 2
+    query_tokens = jnp.concatenate(
+        [q_sal, pick(topic_terms, doc_topic[qrels], n_top_q, topical_terms),
+         zipf((n_queries, query_len - n_sal_q - n_top_q))], 1)
+    return Corpus(doc_emb=doc_emb, doc_tokens=doc_tokens.astype(jnp.int32),
+                  query_emb=query_emb, query_tokens=query_tokens,
+                  qrels=qrels, doc_topic=doc_topic, is_hard=is_hard,
+                  vocab_size=vocab_size)
+
+
 def hard_negatives(corpus: Corpus, n_neg: int, seed: int = 0) -> np.ndarray:
     """Topic-matched hard negatives for distillation training.
 

@@ -18,7 +18,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from repro.distributed import compat
 
 PyTree = Any
 
@@ -33,7 +32,7 @@ def hierarchical_allreduce(grads: PyTree, data_axis: str = "data",
 
     Falls back to a flat psum for leaves too small to scatter.
     """
-    data_size = compat.axis_size(data_axis)
+    data_size = jax.lax.axis_size(data_axis)
 
     def one(g):
         if g.ndim == 0 or g.shape[0] % data_size != 0:

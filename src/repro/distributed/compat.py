@@ -1,59 +1,20 @@
-"""Version tolerance for the JAX SPMD API surface (DESIGN.md §6).
+"""Mesh construction for the SPMD code (DESIGN.md §6).
 
-The distributed code targets the modern spelling (``jax.shard_map``,
-``jax.sharding.AxisType``, ``check_vma=``) but must also run on the
-pinned 0.4.x jaxlib baked into the accelerator image, where the same
-functionality lives under ``jax.experimental.shard_map`` with
-``check_rep=`` and meshes have no axis types.  Every call site goes
-through these two wrappers instead of importing jax directly, so a
-toolchain bump is a one-file change.
+``jax.make_mesh`` gives every axis the ``Explicit`` type by default; the
+``shard_map`` and GSPMD code here is written for ``Auto`` axes, so every
+mesh is built through :func:`make_mesh`.
 """
 from __future__ import annotations
 
 from typing import Optional, Sequence
 
 import jax
-from jax.sharding import Mesh
-
-try:  # jax >= 0.6: top-level export, replication check renamed to vma
-    from jax import shard_map as _shard_map
-    _CHECK_KW = "check_vma"
-except ImportError:  # 0.4.x fallback
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _CHECK_KW = "check_rep"
-
-try:  # explicit-sharding era meshes carry per-axis types
-    from jax.sharding import AxisType as _AxisType
-except ImportError:
-    _AxisType = None
-
-
-def shard_map(f, mesh: Mesh, in_specs, out_specs, check: bool = True):
-    """``jax.shard_map`` with the replication-check kwarg spelled for
-    whichever jax is installed (``check_vma`` / ``check_rep``)."""
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **{_CHECK_KW: check})
-
-
-def axis_size(axis_name) -> jax.Array:
-    """Size of a shard_map/pmap axis from inside the mapped body
-    (``jax.lax.axis_size`` where available, psum-of-ones otherwise)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
+from jax.sharding import AxisType, Mesh
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str],
               *, devices: Optional[Sequence] = None) -> Mesh:
-    """``jax.make_mesh`` pinned to Auto axis types where the installed
-    jax distinguishes them (shard_map + GSPMD code here assumes Auto)."""
-    kwargs = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    if _AxisType is not None:
-        kwargs["axis_types"] = (_AxisType.Auto,) * len(axis_shapes)
-    try:
-        return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
-    except TypeError:  # installed jax.make_mesh predates axis_types kwarg
-        kwargs.pop("axis_types", None)
-        return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
+    """``jax.make_mesh`` with every axis of type ``Auto``."""
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         axis_types=(AxisType.Auto,) * len(axis_shapes),
+                         devices=devices)

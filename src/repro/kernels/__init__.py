@@ -14,7 +14,27 @@
                       architecture backbones (beyond-paper optimization).
 
 Every kernel ships ``kernel.py`` (pl.pallas_call + explicit BlockSpec
-VMEM tiling), ``ops.py`` (jit'd public wrapper with an ``interpret``
-switch so CPU CI exercises the kernel body), and ``ref.py`` (pure-jnp
-oracle used by the tests' assert_allclose sweeps).
+VMEM tiling), ``ops.py`` (jit'd public wrapper whose ``interpret`` flag
+comes from :func:`interpret_mode`, so CPU CI exercises the kernel
+body), and ``ref.py`` (pure-jnp oracle used by the tests'
+assert_allclose sweeps).  ``row_gather`` is the fused scorers' shared
+in-kernel DMA row gather.
 """
+from __future__ import annotations
+
+import jax
+
+
+def interpret_mode() -> bool:
+    """The one compile-or-interpret decision for every Pallas kernel:
+    compile for the TPU, interpret on the CPU backend (tests, CI), and
+    refuse anything else — a kernel must never fall back to the
+    interpreter on a device it was not written for."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas TPU kernels run compiled on a TPU or interpreted on the "
+        f"CPU backend; the default backend here is {backend!r}")

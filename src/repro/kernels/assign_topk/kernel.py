@@ -29,8 +29,9 @@ def _assign_kernel(x_ref, c_ref, best_s_ref, best_i_ref, *, l_blk: int):
     c = c_ref[...].astype(jnp.float32)            # (l_blk, h)
     c_norm = 0.5 * jnp.sum(c * c, axis=-1)        # (l_blk,)
     s = jnp.dot(x, c.T, preferred_element_type=jnp.float32) - c_norm[None, :]
-    local_s = jnp.max(s, axis=-1)
-    local_i = jnp.argmax(s, axis=-1).astype(jnp.int32) + j * l_blk
+    local_s = jnp.max(s, axis=-1, keepdims=True)          # (n_blk, 1)
+    local_i = (jnp.argmax(s, axis=-1, keepdims=True).astype(jnp.int32)
+               + j * l_blk)
 
     @pl.when(j == 0)
     def _init():
@@ -85,7 +86,8 @@ def _topk_kernel(x_ref, e_ref, best_s_ref, best_i_ref, *, k: int,
     j = pl.program_id(1)
     x = x_ref[...].astype(jnp.float32)            # (n_blk, h)
     e = e_ref[...].astype(jnp.float32)            # (l_blk, h)
-    s = jnp.dot(x, e.T, preferred_element_type=jnp.float32)
+    s = jnp.dot(x, e.T, precision=jax.lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32)
     col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + j * l_blk
     s = jnp.where(col < l_true, s, -jnp.inf)      # mask padded columns
 
@@ -147,13 +149,15 @@ def assign_argmax(x: jax.Array, centroids: jax.Array, *, n_blk: int = 256,
     """x: (N, h); centroids: (L, h) → (best_score (N,), best_idx (N,)).
 
     argmax_j ⟨x, c_j⟩ − ½‖c_j‖²  ==  argmin_j ‖x − c_j‖².
-    N % n_blk == 0 and L % l_blk == 0 (ops.py pads).
+    N % n_blk == 0 and L % l_blk == 0 (ops.py pads).  The outputs leave
+    as (N, 1) columns: a TPU block of a 1-D array must match its
+    (1024-element) HBM tiling, an (n_blk, 1) block of a column need not.
     """
     n, h = x.shape
     l, _ = centroids.shape
     assert n % n_blk == 0 and l % l_blk == 0, (n, n_blk, l, l_blk)
     grid = (n // n_blk, l // l_blk)
-    return pl.pallas_call(
+    s, i = pl.pallas_call(
         functools.partial(_assign_kernel, l_blk=l_blk),
         grid=grid,
         in_specs=[
@@ -161,12 +165,13 @@ def assign_argmax(x: jax.Array, centroids: jax.Array, *, n_blk: int = 256,
             pl.BlockSpec((l_blk, h), lambda i, j: (j, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((n_blk,), lambda i, j: (i,)),
-            pl.BlockSpec((n_blk,), lambda i, j: (i,)),
+            pl.BlockSpec((n_blk, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((n_blk, 1), lambda i, j: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((n,), jnp.float32),
-            jax.ShapeDtypeStruct((n,), jnp.int32),
+            jax.ShapeDtypeStruct((n, 1), jnp.float32),
+            jax.ShapeDtypeStruct((n, 1), jnp.int32),
         ],
         interpret=interpret,
     )(x, centroids)
+    return s[:, 0], i[:, 0]

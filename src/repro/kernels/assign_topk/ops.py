@@ -1,5 +1,6 @@
 """Public wrapper: pads N/L to tile multiples, strips the padding, and
-switches to interpret mode off-TPU."""
+takes the compile-or-interpret decision of
+:func:`repro.kernels.interpret_mode`."""
 from __future__ import annotations
 
 import functools
@@ -7,11 +8,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.assign_topk import kernel, ref
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("n_blk", "l_blk", "use_kernel"))
@@ -35,7 +33,7 @@ def assign_argmax(x: jax.Array, centroids: jax.Array, *, n_blk: int = 256,
         [centroids, jnp.broadcast_to(centroids[:1], (pad_l, h))])
         if pad_l else centroids)
     s, i = kernel.assign_argmax(xp, cp, n_blk=n_blk, l_blk=l_blk,
-                                interpret=not _on_tpu())
+                                interpret=interpret_mode())
     return s[:n], i[:n]
 
 
@@ -64,5 +62,5 @@ def topk_scores(x: jax.Array, emb: jax.Array, k: int, *, n_blk: int = 256,
     xp = jnp.pad(x, ((0, pad_n), (0, 0)))
     ep = jnp.pad(emb, ((0, pad_l), (0, 0)))
     s, i = kernel.topk_scores(xp, ep, k=k, n_blk=n_blk, l_blk=l_blk,
-                              l_true=l, interpret=not _on_tpu())
+                              l_true=l, interpret=interpret_mode())
     return s[:n], i[:n]

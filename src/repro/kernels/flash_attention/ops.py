@@ -1,5 +1,6 @@
 """Public flash-attention wrapper: pads sequence dims to tile multiples,
-switches to interpret mode off-TPU, and exposes a differentiable op —
+compiles or interprets per :func:`repro.kernels.interpret_mode`, and
+exposes a differentiable op —
 the forward is the Pallas kernel; the backward is the XLA-native
 recompute gradient of the oracle (the paper's serving regime never
 backprops through attention; training falls back to a fused-by-XLA path,
@@ -11,11 +12,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.flash_attention import kernel, ref
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
@@ -37,7 +35,7 @@ def _forward(q, k, v, causal, window, scale):
     # true lengths are carried via sq/sk inside the kernel mask
     out, _ = kernel.flash_attention(
         qp, kp, vp, causal=causal, window=window, scale=scale,
-        blk_q=blk_q, blk_k=blk_k, interpret=not _on_tpu())
+        blk_q=blk_q, blk_k=blk_k, interpret=interpret_mode())
     # kernel masks by absolute position, but padded q rows still emit
     out = out[:, :, :sq]
     return out

@@ -5,22 +5,22 @@ document is m uint8/int32 codes; its score is Σ_j lut[j, code_j].
 
 GPU/Faiss does this with SIMD gathers through L1.  TPUs have no fast
 per-lane gather from VMEM, so we *reformulate the gather as a one-hot
-contraction* that runs on the MXU/VPU:
+selection* on the VPU, candidates on lanes:
 
-    score(c) = Σ_j  onehot(code_cj) · lut[j]        (k-wide dot)
+    score(c) = Σ_j  Σ_i [code_cj == i] · lut[j, i]      (exact: one hit)
 
-Layout: codes arrive **fragment-major** ``(B, m, C)`` (the transpose is
-done once at index-build; Faiss uses the same interleaved layout for its
-SIMD path).  Candidate tiles of 128 keep every intermediate 128-lane
-aligned; the one-hot plane per fragment is (C_blk, k) f32 = 128 KiB for
-k=256 — far under VMEM even with double buffering.
+Layout: codes arrive **fragment-major** ``(B, m, C)`` and the LUT
+transposed to ``(B, k, m)``, so one fragment's codes are a lane row and
+one fragment's LUT column broadcasts across lanes; the one-hot plane
+per fragment is (k, C_blk).  Scores leave through a ``(B, 1, C)`` plane
+whose ``(1, 1, C_blk)`` blocks satisfy the TPU's (8, 128) block rule.
 
-Grid: (B, C / C_blk); the LUT block (1, m, k) is revisited across the
+Grid: (B, C / C_blk); the LUT block (1, k, m) is revisited across the
 candidate dimension so it stays resident in VMEM for the whole query.
 
 VMEM budget per grid step (m=96, k=256, C_blk=512):
     lut 96·256·4 = 98 KiB, codes 96·512·4 = 196 KiB,
-    onehot 512·256·4 = 512 KiB, out 2 KiB   → ≈ 0.8 MiB ≪ 16 MiB.
+    onehot 256·512·4 = 512 KiB, out 2 KiB   → ≈ 0.8 MiB ≪ 16 MiB.
 """
 from __future__ import annotations
 
@@ -31,17 +31,28 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import row_gather
+
+
+def _adc_accumulate(codes_t, lut_t, m: int, k: int, c_blk: int):
+    """Σ_j lut[j, codes[j, c]] for each candidate lane c.
+
+    ``codes_t``: (≥m, c_blk) i32, candidates on lanes; ``lut_t``: (k, m)
+    f32.  Fragment by fragment, the one-hot selects the LUT column on
+    the VPU and a sublane sum extracts it — exact (one nonzero term per
+    lane), so accumulation order is j = 0..m-1 exactly as in the
+    unfused reference formulation."""
+    kio = jax.lax.broadcasted_iota(jnp.int32, (k, c_blk), 0)
+    acc = jnp.zeros((1, c_blk), jnp.float32)
+    for j in range(m):        # static unroll — m ≤ 96
+        hit = codes_t[j:j + 1, :] == kio                       # (k, c_blk)
+        acc = acc + jnp.sum(jnp.where(hit, lut_t[:, j:j + 1], 0.0),
+                            axis=0, keepdims=True)
+    return acc
+
 
 def _adc_kernel(lut_ref, codes_ref, out_ref, *, m: int, k: int, c_blk: int):
-    lut = lut_ref[0]          # (m, k) f32
-    codes = codes_ref[0]      # (m, c_blk) i32
-    acc = jnp.zeros((c_blk,), jnp.float32)
-    iota = jax.lax.broadcasted_iota(jnp.int32, (c_blk, k), 1)
-    for j in range(m):        # static unroll — m ≤ 96
-        onehot = (codes[j][:, None] == iota).astype(jnp.float32)  # (c_blk, k)
-        acc = acc + jnp.dot(onehot, lut[j],
-                            preferred_element_type=jnp.float32)
-    out_ref[0] = acc
+    out_ref[0] = _adc_accumulate(codes_ref[0], lut_ref[0], m, k, c_blk)
 
 
 @functools.partial(jax.jit, static_argnames=("c_blk", "interpret"))
@@ -50,22 +61,24 @@ def pq_adc_fragmajor(lut: jax.Array, codes_fm: jax.Array, *,
     """lut: (B, m, k) f32; codes_fm: (B, m, C) i32 → scores (B, C) f32.
 
     C must be a multiple of ``c_blk`` (ops.py pads); k a multiple of 128.
+    Scores leave through a (B, 1, C) plane: a (1, 1, c_blk) block is
+    tile-legal where a (1, c_blk) block of (B, C) is not.
     """
     b, m, k = lut.shape
     _, _, c = codes_fm.shape
     assert c % c_blk == 0, (c, c_blk)
-    grid = (b, c // c_blk)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_adc_kernel, m=m, k=k, c_blk=c_blk),
-        grid=grid,
+        grid=(b, c // c_blk),
         in_specs=[
-            pl.BlockSpec((1, m, k), lambda bi, ci: (bi, 0, 0)),
+            pl.BlockSpec((1, k, m), lambda bi, ci: (bi, 0, 0)),
             pl.BlockSpec((1, m, c_blk), lambda bi, ci: (bi, 0, ci)),
         ],
-        out_specs=pl.BlockSpec((1, c_blk), lambda bi, ci: (bi, ci)),
-        out_shape=jax.ShapeDtypeStruct((b, c), jnp.float32),
+        out_specs=pl.BlockSpec((1, 1, c_blk), lambda bi, ci: (bi, 0, ci)),
+        out_shape=jax.ShapeDtypeStruct((b, 1, c), jnp.float32),
         interpret=interpret,
-    )(lut, codes_fm)
+    )(jnp.swapaxes(lut, 1, 2), codes_fm)
+    return out.reshape(b, c)
 
 
 # --------------------------------------------------------------------------
@@ -77,55 +90,31 @@ def pq_adc_fragmajor(lut: jax.Array, codes_fm: jax.Array, *,
 # (N, m) plane), then streams that plane back through the ADC kernel —
 # 2× the HBM traffic of the codes actually scored, plus the intermediate
 # itself.  The fused kernel takes the *resident* plane and the (B, C)
-# candidate ids and performs the row gather inside the kernel body:
+# candidate ids and performs the row gather inside the kernel body
+# (:mod:`repro.kernels.row_gather`):
 #
-#   · ids are scalar-prefetched (SMEM), so each row's HBM address is
-#     known before the compute step runs;
-#   · the codes plane stays in HBM (memory_space=ANY) and candidate
-#     rows are DMA'd into a (c_blk, m) VMEM scratch, double-buffered so
-#     row i+1 is in flight while row i lands;
+#   · each grid step's c_blk candidate ids arrive in SMEM, so every
+#     row's HBM address is known to the scalar core;
+#   · the codes plane stays in HBM (memory_space=ANY); each candidate's
+#     8-row tile group is DMA'd into VMEM, double-buffered, and its row
+#     extracted into a (c_blk, w) int32 tile;
 #   · the live mask (dedup ∧ ¬tombstone ∧ namespace) is applied
 #     in-kernel: masked lanes leave as -inf, so the (B, C) score plane
 #     that reaches HBM is already selection-ready.
 #
 # Nothing of shape (B, C, m) ever exists — asserted over the jaxpr by
 # tests/test_kernels.py.  Per-candidate accumulation order (fragment
-# j = 0..m-1, one-hot dot per fragment) is identical to `_adc_kernel`,
+# j = 0..m-1, :func:`_adc_accumulate`) is identical to `_adc_kernel`,
 # so fused and unfused *kernel* scores agree bitwise; only the pure-jnp
 # oracle's m-reduction order differs (DESIGN.md §11 bounds it).
 
 
 def _adc_fused_kernel(ids_ref, lut_ref, live_ref, plane_ref, out_ref,
-                      codes_sc, sems, *, m: int, k: int, c_blk: int):
-    b, ci = pl.program_id(0), pl.program_id(1)
-    base = ci * c_blk
-
-    def row_copy(i, slot):
-        idx = ids_ref[b, base + i]
-        return pltpu.make_async_copy(plane_ref.at[pl.ds(idx, 1)],
-                                     codes_sc.at[pl.ds(i, 1)],
-                                     sems.at[slot])
-
-    row_copy(0, 0).start()
-
-    def gather_body(i, _):
-        @pl.when(i + 1 < c_blk)
-        def _prefetch():
-            row_copy(i + 1, (i + 1) % 2).start()
-
-        row_copy(i, i % 2).wait()
-        return 0
-
-    jax.lax.fori_loop(0, c_blk, gather_body, 0)
-
-    lut = lut_ref[0]                                   # (m, k) f32
-    codes = codes_sc[...].astype(jnp.int32)            # (c_blk, m)
-    acc = jnp.zeros((c_blk,), jnp.float32)
-    iota = jax.lax.broadcasted_iota(jnp.int32, (c_blk, k), 1)
-    for j in range(m):        # static unroll — same order as _adc_kernel
-        onehot = (codes[:, j][:, None] == iota).astype(jnp.float32)
-        acc = acc + jnp.dot(onehot, lut[j],
-                            preferred_element_type=jnp.float32)
+                      groups_sc, rows_sc, sems, *, m: int, k: int,
+                      c_blk: int):
+    row_gather.gather_rows(ids_ref, plane_ref, groups_sc, rows_sc, sems,
+                           c_blk)
+    acc = _adc_accumulate(rows_sc[...].T, lut_ref[0], m, k, c_blk)
     out_ref[0] = jnp.where(live_ref[0] != 0, acc, -jnp.inf)
 
 
@@ -133,36 +122,34 @@ def _adc_fused_kernel(ids_ref, lut_ref, live_ref, plane_ref, out_ref,
 def pq_adc_fused(lut: jax.Array, codes_plane: jax.Array, ids: jax.Array,
                  live: jax.Array, *, c_blk: int = 256,
                  interpret: bool = False) -> jax.Array:
-    """lut: (B, m, k) f32; codes_plane: (N, m) int; ids: (B, C) i32 in
-    [0, N); live: (B, C) i32 (0 = masked) → scores (B, C) f32, ``-inf``
-    on masked lanes.
+    """lut: (B, m, k) f32; codes_plane: (N, w) int, N % 8 == 0,
+    w % 128 == 0, w ≥ m; ids: (B, C) i32 in [0, N); live: (B, C) i32
+    (0 = masked) → scores (B, C) f32, ``-inf`` on masked lanes.
 
-    C must be a multiple of ``c_blk`` and k of 128 (ops.py pads both).
-    The codes plane keeps its storage dtype (uint8 when k ≤ 256) all
-    the way into VMEM; widening to i32 happens on-chip.
+    C must be a multiple of ``c_blk`` and k of 128 (ops.py pads all of
+    these).  The codes plane keeps its storage dtype (uint8 when
+    k ≤ 256) all the way into VMEM; widening to i32 happens on-chip.
     """
     b, m, k = lut.shape
-    n = codes_plane.shape[0]
     _, c = ids.shape
     assert c % c_blk == 0, (c, c_blk)
-    del n
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b, c // c_blk),
-        in_specs=[
-            pl.BlockSpec((1, m, k), lambda bi, ci, ids_ref: (bi, 0, 0)),
-            pl.BlockSpec((1, c_blk), lambda bi, ci, ids_ref: (bi, ci)),
-            pl.BlockSpec(memory_space=pltpu.ANY),      # resident plane
-        ],
-        out_specs=pl.BlockSpec((1, c_blk), lambda bi, ci, ids_ref: (bi, ci)),
-        scratch_shapes=[
-            pltpu.VMEM((c_blk, m), codes_plane.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
-    )
-    return pl.pallas_call(
+    n_blk = c // c_blk
+    out = pl.pallas_call(
         functools.partial(_adc_fused_kernel, m=m, k=k, c_blk=c_blk),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, c), jnp.float32),
+        grid=(b, n_blk),
+        in_specs=[
+            pl.BlockSpec((1, 1, c_blk),
+                         lambda bi, ci: (bi * n_blk + ci, 0, 0),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, k, m), lambda bi, ci: (bi, 0, 0)),
+            pl.BlockSpec((1, 1, c_blk), lambda bi, ci: (bi, 0, ci)),
+            pl.BlockSpec(memory_space=pl.ANY),         # resident plane
+        ],
+        out_specs=pl.BlockSpec((1, 1, c_blk), lambda bi, ci: (bi, 0, ci)),
+        out_shape=jax.ShapeDtypeStruct((b, 1, c), jnp.float32),
+        scratch_shapes=row_gather.scratch_shapes(
+            c_blk, codes_plane.shape[1], codes_plane.dtype),
         interpret=interpret,
-    )(ids, lut, live, codes_plane)
+    )(ids.reshape(b * n_blk, 1, c_blk), jnp.swapaxes(lut, 1, 2),
+      live.reshape(b, 1, c), codes_plane)
+    return out.reshape(b, c)

@@ -1,9 +1,10 @@
 """Public jit'd wrapper for the PQ ADC kernel.
 
 Handles layout (candidate-major → fragment-major), padding C to the tile
-size, and the CPU/TPU switch: on non-TPU backends the pallas_call runs in
-``interpret=True`` mode (the kernel body executed by XLA:CPU) so the same
-code path is exercised everywhere.
+size and the codes plane to whole tiles, and the CPU/TPU switch
+(:func:`repro.kernels.interpret_mode`): on the CPU backend the
+pallas_call runs in ``interpret=True`` mode (the kernel body executed by
+XLA:CPU) so the same code path is exercised everywhere.
 """
 from __future__ import annotations
 
@@ -12,11 +13,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode, row_gather
 from repro.kernels.pq_adc import kernel, ref
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("c_blk", "use_kernel"))
@@ -31,7 +29,7 @@ def pq_adc(lut: jax.Array, codes: jax.Array, *, c_blk: int = 512,
     if pad:
         codes_fm = jnp.pad(codes_fm, ((0, 0), (0, 0), (0, pad)))
     out = kernel.pq_adc_fragmajor(lut, codes_fm, c_blk=c_blk,
-                                  interpret=not _on_tpu())
+                                  interpret=interpret_mode())
     return out[:, :c]
 
 
@@ -51,7 +49,10 @@ def pq_adc_fused(lut: jax.Array, codes_plane: jax.Array, ids: jax.Array,
       · C → multiple of ``c_blk`` with ids=0 / live=0 (rows stripped
         after the call; id 0 keeps the in-kernel DMA in bounds);
       · k → multiple of 128 with zero LUT columns (codes < k never
-        select them).
+        select them);
+      · the plane → whole (8, 128) tiles (:func:`row_gather.pad_plane`;
+        a no-op for 128-lane planes of 8k rows, a per-call copy for
+        narrower ones such as m=96).
     """
     if not use_kernel:
         return ref.pq_adc_fused(lut, codes_plane, ids, live)
@@ -66,6 +67,7 @@ def pq_adc_fused(lut: jax.Array, codes_plane: jax.Array, ids: jax.Array,
     if c_pad:
         ids = jnp.pad(ids, ((0, 0), (0, c_pad)))
         live = jnp.pad(live, ((0, 0), (0, c_pad)))
-    out = kernel.pq_adc_fused(lut, codes_plane, ids, live, c_blk=c_blk,
-                              interpret=not _on_tpu())
+    out = kernel.pq_adc_fused(lut, row_gather.pad_plane(codes_plane), ids,
+                              live, c_blk=c_blk,
+                              interpret=interpret_mode())
     return out[:, :c]

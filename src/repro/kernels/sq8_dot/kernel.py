@@ -4,9 +4,9 @@ SQ8 scoring is ⟨q·scale, code⟩ + ⟨q, lo⟩: a pre-scaled dot over the
 gathered byte rows plus a per-query bias.  The unfused path gathers the
 (B, C, h) byte rows in HBM first; this kernel keeps the (N, h) codes
 plane resident in HBM and DMAs candidate rows straight into VMEM —
-the same scalar-prefetch + double-buffered-copy structure as
+the shared :mod:`repro.kernels.row_gather` as in
 ``pq_adc/kernel._adc_fused_kernel``, with the one-hot ADC loop replaced
-by a single (c_blk, h)·(h,) MXU dot.
+by a single (1, h)·(c_blk, h)ᵀ MXU dot at full f32 precision.
 
 The live mask is applied in-kernel (-inf); the per-query bias is added
 *outside* by the caller after masking (-inf + bias = -inf, so masked
@@ -22,33 +22,19 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import row_gather
+
 
 def _sq8_fused_kernel(ids_ref, q_ref, live_ref, plane_ref, out_ref,
-                      rows_sc, sems, *, h: int, c_blk: int):
-    b, ci = pl.program_id(0), pl.program_id(1)
-    base = ci * c_blk
-
-    def row_copy(i, slot):
-        idx = ids_ref[b, base + i]
-        return pltpu.make_async_copy(plane_ref.at[pl.ds(idx, 1)],
-                                     rows_sc.at[pl.ds(i, 1)],
-                                     sems.at[slot])
-
-    row_copy(0, 0).start()
-
-    def gather_body(i, _):
-        @pl.when(i + 1 < c_blk)
-        def _prefetch():
-            row_copy(i + 1, (i + 1) % 2).start()
-
-        row_copy(i, i % 2).wait()
-        return 0
-
-    jax.lax.fori_loop(0, c_blk, gather_body, 0)
-
-    q = q_ref[0]                                       # (h,) f32, pre-scaled
+                      groups_sc, rows_sc, sems, *, c_blk: int):
+    row_gather.gather_rows(ids_ref, plane_ref, groups_sc, rows_sc, sems,
+                           c_blk)
+    q = q_ref[0]                                       # (1, h) f32, pre-scaled
     rows = rows_sc[...].astype(jnp.float32)            # (c_blk, h)
-    acc = jnp.dot(rows, q, preferred_element_type=jnp.float32)
+    acc = jax.lax.dot_general(                         # (1, c_blk)
+        q, rows, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
     out_ref[0] = jnp.where(live_ref[0] != 0, acc, -jnp.inf)
 
 
@@ -56,29 +42,33 @@ def _sq8_fused_kernel(ids_ref, q_ref, live_ref, plane_ref, out_ref,
 def sq8_dot_fused(q_scaled: jax.Array, codes_plane: jax.Array,
                   ids: jax.Array, live: jax.Array, *, c_blk: int = 256,
                   interpret: bool = False) -> jax.Array:
-    """q_scaled: (B, h) f32; codes_plane: (N, h) u8; ids: (B, C) i32 in
+    """q_scaled: (B, h) f32; codes_plane: (N, w) u8, N % 8 == 0,
+    w % 128 == 0, w ≥ h (zero-padded lanes); ids: (B, C) i32 in
     [0, N); live: (B, C) i32 → (B, C) f32 bias-free scores, ``-inf`` on
     masked lanes.  C must be a multiple of ``c_blk`` (ops.py pads)."""
     b, h = q_scaled.shape
     _, c = ids.shape
+    w = codes_plane.shape[1]
     assert c % c_blk == 0, (c, c_blk)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b, c // c_blk),
+    n_blk = c // c_blk
+    if w != h:
+        q_scaled = jnp.pad(q_scaled, ((0, 0), (0, w - h)))
+    out = pl.pallas_call(
+        functools.partial(_sq8_fused_kernel, c_blk=c_blk),
+        grid=(b, n_blk),
         in_specs=[
-            pl.BlockSpec((1, h), lambda bi, ci, ids_ref: (bi, 0)),
-            pl.BlockSpec((1, c_blk), lambda bi, ci, ids_ref: (bi, ci)),
-            pl.BlockSpec(memory_space=pltpu.ANY),      # resident plane
+            pl.BlockSpec((1, 1, c_blk),
+                         lambda bi, ci: (bi * n_blk + ci, 0, 0),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, 1, w), lambda bi, ci: (bi, 0, 0)),
+            pl.BlockSpec((1, 1, c_blk), lambda bi, ci: (bi, 0, ci)),
+            pl.BlockSpec(memory_space=pl.ANY),         # resident plane
         ],
-        out_specs=pl.BlockSpec((1, c_blk), lambda bi, ci, ids_ref: (bi, ci)),
-        scratch_shapes=[
-            pltpu.VMEM((c_blk, h), codes_plane.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
-    )
-    return pl.pallas_call(
-        functools.partial(_sq8_fused_kernel, h=h, c_blk=c_blk),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, c), jnp.float32),
+        out_specs=pl.BlockSpec((1, 1, c_blk), lambda bi, ci: (bi, 0, ci)),
+        out_shape=jax.ShapeDtypeStruct((b, 1, c), jnp.float32),
+        scratch_shapes=row_gather.scratch_shapes(c_blk, w,
+                                                 codes_plane.dtype),
         interpret=interpret,
-    )(ids, q_scaled, live, codes_plane)
+    )(ids.reshape(b * n_blk, 1, c_blk), q_scaled.reshape(b, 1, w),
+      live.reshape(b, 1, c), codes_plane)
+    return out.reshape(b, c)

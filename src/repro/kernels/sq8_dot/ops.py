@@ -1,6 +1,8 @@
 """Public jit'd wrapper for the fused SQ8 gather+dot kernel: pads C to
-the tile size, clips ids defensively, and switches to interpret mode
-off-TPU so CPU CI runs the same kernel body."""
+the tile size and the plane to whole tiles, clips ids defensively, and
+takes the compile-or-interpret decision of
+:func:`repro.kernels.interpret_mode` so CPU CI runs the same kernel
+body."""
 from __future__ import annotations
 
 import functools
@@ -8,11 +10,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode, row_gather
 from repro.kernels.sq8_dot import kernel, ref
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("c_blk", "use_kernel"))
@@ -35,6 +34,7 @@ def sq8_dot_fused(q_scaled: jax.Array, codes_plane: jax.Array,
     if c_pad:
         ids = jnp.pad(ids, ((0, 0), (0, c_pad)))
         live = jnp.pad(live, ((0, 0), (0, c_pad)))
-    out = kernel.sq8_dot_fused(q_scaled, codes_plane, ids, live,
-                               c_blk=c_blk, interpret=not _on_tpu())
+    out = kernel.sq8_dot_fused(q_scaled, row_gather.pad_plane(codes_plane),
+                               ids, live, c_blk=c_blk,
+                               interpret=interpret_mode())
     return out[:, :c]

@@ -63,8 +63,10 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import os
 import sys
 import time
+from pathlib import Path
 from typing import Optional
 
 import jax
@@ -552,7 +554,25 @@ def make_mutable_server(mut: seg.MutableHybridIndex,
     return MutableServer(mut, cfg)
 
 
+#: the entry points' compile cache when JAX_COMPILATION_CACHE_DIR is not
+#: set: a fixed path inside the checkout, so a later run finds it again
+DEFAULT_COMPILE_CACHE = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for an entry point;
+    returns its directory.  ``JAX_COMPILATION_CACHE_DIR``, when set, is
+    used as JAX reads it and nothing else is set; otherwise the cache
+    goes to :data:`DEFAULT_COMPILE_CACHE`."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", str(DEFAULT_COMPILE_CACHE))
+    return str(DEFAULT_COMPILE_CACHE)
+
+
 def main(argv: Optional[list] = None) -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description="HI² serving demo loop")
     ap.add_argument("--shards", type=int, default=1,
                     help="document shards (devices); on CPU emulate with "

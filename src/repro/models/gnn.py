@@ -191,7 +191,6 @@ def forward_partitioned(params: dict, cfg: GatedGCNConfig,
     off-mesh."""
     from jax.sharding import PartitionSpec as P
     from repro.distributed import sharding as shd
-    from repro.distributed.compat import shard_map as _shard_map
 
     mesh = shd._mesh()
     if mesh is None:
@@ -246,11 +245,11 @@ def forward_partitioned(params: dict, cfg: GatedGCNConfig,
         return layers.dense(p["head"], h)                    # (n_local, C)
 
     ax = axes if len(axes) > 1 else axes[0]
-    logits = _shard_map(
+    logits = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), P(ax, None), P(ax), P(ax), P(ax)),
         out_specs=P(ax, None),
-        check=False,
+        check_vma=False,
     )(params, batch.node_feat, batch.edge_src, batch.edge_dst,
       batch.edge_mask)
     return logits

@@ -198,7 +198,6 @@ def forward_shard_map(params: dict, x: Array, *, n_experts: int, top_k: int,
     mesh is active (CPU unit tests)."""
     from jax.sharding import PartitionSpec as P
     from repro.distributed import sharding as shd
-    from repro.distributed.compat import shard_map as _shard_map
 
     mesh = shd._mesh()
     if mesh is None:
@@ -240,7 +239,7 @@ def forward_shard_map(params: dict, x: Array, *, n_experts: int, top_k: int,
 
     batch_spec = P(data_axes if len(data_axes) > 1 else data_axes[0],
                    None, None)
-    out, aux, dropped = _shard_map(
+    out, aux, dropped = jax.shard_map(
         body, mesh=mesh,
         in_specs=(batch_spec,
                   P(None, None),                       # router (replicated)
@@ -248,7 +247,7 @@ def forward_shard_map(params: dict, x: Array, *, n_experts: int, top_k: int,
                   P(None, data_axes, "model"),         # w_up
                   P(None, "model", data_axes)),        # w_down (E, F, D)
         out_specs=(batch_spec, P(), P()),
-        check=False,
+        check_vma=False,
     )(x, params["router"]["w"], params["w_gate"], params["w_up"],
       params["w_down"])
     return out, MoEStats(aux_loss=aux, dropped_frac=dropped)

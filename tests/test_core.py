@@ -2,10 +2,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # accelerator image: no pip installs; CI has the real one
-    from _hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import (bm25, cluster_selector as cs, inverted_lists as il,
                         kmeans, pruning, term_selector as ts)

@@ -25,11 +25,10 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.core import kmeans
 from repro.distributed import compat
-from repro.distributed.compat import shard_map
 mesh = compat.make_mesh((8,), ("data",))
 x = jax.random.normal(jax.random.key(0), (1024, 16))
 
-fit = shard_map(
+fit = jax.shard_map(
     lambda xl: kmeans.kmeans_fit_sharded(jax.random.key(1), xl, 8, n_iters=5),
     mesh=mesh, in_specs=P("data"), out_specs=P())
 c_sharded = fit(x)
@@ -48,18 +47,17 @@ def test_hierarchical_allreduce_equals_flat():
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.distributed import collectives, compat
-from repro.distributed.compat import shard_map
 mesh = compat.make_mesh((2, 4), ("pod", "data"))
 g = {"w": jax.random.normal(jax.random.key(0), (16, 8)),
      "b": jax.random.normal(jax.random.key(1), (5,))}   # 5 not divisible by 4
 
-flat = shard_map(
+flat = jax.shard_map(
     lambda t: collectives.flat_allreduce(t, ("data", "pod")),
     mesh=mesh, in_specs=P(("pod", "data")), out_specs=P())
-hier = shard_map(
+hier = jax.shard_map(
     lambda t: collectives.hierarchical_allreduce(t),
     mesh=mesh, in_specs=P(("pod", "data")), out_specs=P(),
-    check=False)  # RS->AR->AG reconstructs replication; not inferable
+    check_vma=False)  # RS->AR->AG reconstructs replication; not inferable
 
 gs = {"w": jnp.tile(g["w"], (8, 1)), "b": jnp.tile(g["b"], 8)}
 a = flat({"w": gs["w"], "b": gs["b"]})

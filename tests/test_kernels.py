@@ -3,10 +3,7 @@ allclose against the pure-jnp oracles, in interpret mode on CPU."""
 import jax
 import jax.numpy as jnp
 import numpy as np
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # accelerator image: no pip installs; CI has the real one
-    from _hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.kernels.assign_topk import ops as at_ops, ref as at_ref
 from repro.kernels.flash_attention import ops as fa_ops, ref as fa_ref

@@ -168,7 +168,11 @@ qe, qt = corpus.query_emb[:16], corpus.query_tokens[:16]
 full = srv.query(qe, qt)
 assert srv.epoch == 0 and not srv.partial
 
-rt = rt_mod.ServingRuntime(srv, rt_mod.RuntimeConfig(cache_size=64))
+# every rt.query below is one full max_batch: a long linger makes it
+# one micro-batch however the scheduler thread is timed (sq8 scores
+# of a smaller bucket may differ from `full` by an ulp)
+rt = rt_mod.ServingRuntime(srv, rt_mod.RuntimeConfig(cache_size=64,
+                                                     linger_ms=60_000))
 rt.warmup(32, qt.shape[1])
 pre = rt.query(qe, qt)
 assert not pre.partial
