@@ -23,6 +23,7 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from repro import spans
 from repro.core import kmeans
 
 Array = jax.Array
@@ -42,12 +43,18 @@ def init_kmeans(key: Array, doc_embeddings: Array, n_clusters: int,
 
     φ(D) is the INNER-PRODUCT argmax over the KMeans centroids (paper
     §4.1: "indexed to the cluster with the highest score" ⟨e_D, e_C⟩) —
-    not the L2 assignment KMeans itself used.
+    not the L2 assignment KMeans itself used.  Host span
+    ``hi2.build.kmeans`` (DESIGN.md §9).
     """
-    centroids, _ = kmeans.kmeans_fit(key, doc_embeddings,
-                                     n_clusters=n_clusters, n_iters=n_iters)
-    selector = ClusterSelector(embeddings=centroids)
-    return selector, select_for_doc(selector, doc_embeddings)
+    with spans.span("hi2.build.kmeans"):
+        centroids, _ = kmeans.kmeans_fit(key, doc_embeddings,
+                                         n_clusters=n_clusters,
+                                         n_iters=n_iters)
+        selector = ClusterSelector(embeddings=centroids)
+        assign = select_for_doc(selector, doc_embeddings)
+        if spans.recording():       # the span ends where the work does
+            jax.block_until_ready((selector, assign))
+    return selector, assign
 
 
 #: documents per row block of the indexing-side score plane: the

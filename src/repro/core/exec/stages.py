@@ -427,6 +427,19 @@ def trace_count() -> int:
 # the engine
 # --------------------------------------------------------------------------
 
+#: the stages of :func:`execute`, each traced under the name scope
+#: ``hi2.<stage>``: the scope rides into the compiled program's
+#: ``op_name`` metadata and the profiler's op names, so device time can
+#: be split by stage (DESIGN.md §9); it changes nothing else
+STAGES = ("dispatch", "gather", "dedup", "filter", "score", "topk",
+          "refine", "sparse", "fuse")
+SCOPE_PREFIX = "hi2."
+
+
+def _scope(stage: str):
+    return jax.named_scope(SCOPE_PREFIX + stage)
+
+
 def execute(codec_impl: codecs_base.Codec, codec_params: Any,
             cluster_sel: cs_mod.ClusterSelector,
             term_sel: ts_mod.TermSelector,
@@ -456,33 +469,49 @@ def execute(codec_impl: codecs_base.Codec, codec_params: Any,
     """
     global _TRACES
     _TRACES += 1
-    cluster_ids, term_ids = dispatch(cluster_sel, term_sel,
-                                     query_embeddings, query_tokens, kc, k2,
-                                     use_kernel)
-    frontier = gather(sources, cluster_ids, term_ids)
-    keep = dedup(frontier)
-    frontier.live = filter_stage(frontier, sources, keep, ns_filter)
-    frontier.scores = score(codec_impl, codec_params, sources, frontier,
-                            frontier.live, query_embeddings, use_kernel)
-    top_s, top_ids = topk(frontier, codec_impl.refine_width(top_r), shard)
-    top_s, top_ids = codec_impl.refine(
-        codec_params, refine_planes(sources), query_embeddings,
-        top_s, top_ids, top_r, make_refine_ctx(sources, shard))
+    with _scope("dispatch"):
+        cluster_ids, term_ids = dispatch(cluster_sel, term_sel,
+                                         query_embeddings, query_tokens,
+                                         kc, k2, use_kernel)
+    with _scope("gather"):
+        frontier = gather(sources, cluster_ids, term_ids)
+    with _scope("dedup"):
+        keep = dedup(frontier)
+    with _scope("filter"):
+        frontier.live = filter_stage(frontier, sources, keep, ns_filter)
+    with _scope("score"):
+        frontier.scores = score(codec_impl, codec_params, sources, frontier,
+                                frontier.live, query_embeddings, use_kernel)
+    with _scope("topk"):
+        top_s, top_ids = topk(frontier, codec_impl.refine_width(top_r),
+                              shard)
+    with _scope("refine"):
+        top_s, top_ids = codec_impl.refine(
+            codec_params, refine_planes(sources), query_embeddings,
+            top_s, top_ids, top_r, make_refine_ctx(sources, shard))
 
     fused = (fusion is not None
              and all(s.sparse_weights is not None for s in sources))
     if fused:
-        sp_s, sp_ids, n_sparse = sparse_topk(sources, term_ids, top_r,
-                                             ns_filter, shard)
-        top_s, top_ids = fuse(top_s, top_ids, sp_s, sp_ids, fusion, top_r)
+        with _scope("sparse"):
+            sp_s, sp_ids, n_sparse = sparse_topk(sources, term_ids, top_r,
+                                                 ns_filter, shard)
+        with _scope("fuse"):
+            top_s, top_ids = fuse(top_s, top_ids, sp_s, sp_ids, fusion,
+                                  top_r)
 
-    n_cand = frontier.live.sum(axis=-1).astype(jnp.int32)
-    if shard is not None:
-        n_cand = jax.lax.psum(n_cand, shard.axis_name)
+    # the live-candidate count belongs to the filter stage, the final
+    # masking of the answer to the stage that produced it
+    with _scope("filter"):
+        n_cand = frontier.live.sum(axis=-1).astype(jnp.int32)
+        if shard is not None:
+            n_cand = jax.lax.psum(n_cand, shard.axis_name)
     if fused:
-        n_cand = n_cand + n_sparse
-    valid = jnp.isfinite(top_s)
-    return SearchResult(
-        doc_ids=jnp.where(valid, top_ids, PAD_DOC).astype(jnp.int32),
-        scores=jnp.where(valid, top_s, 0.0),
-        n_candidates=n_cand)
+        with _scope("sparse"):
+            n_cand = n_cand + n_sparse
+    with _scope("fuse" if fused else "refine"):
+        valid = jnp.isfinite(top_s)
+        return SearchResult(
+            doc_ids=jnp.where(valid, top_ids, PAD_DOC).astype(jnp.int32),
+            scores=jnp.where(valid, top_s, 0.0),
+            n_candidates=n_cand)

@@ -43,6 +43,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import spans
 from repro.core import cluster_selector as cs_mod
 from repro.core import codecs
 from repro.core import exec as qexec
@@ -101,6 +102,7 @@ class HybridIndex:
 # build
 # --------------------------------------------------------------------------
 
+@spans.span("hi2.build")
 def build(key: Array,
           doc_embeddings: Array,
           doc_tokens: Array,
@@ -138,6 +140,11 @@ def build(key: Array,
     lists, enabling hybrid search via ``search(fusion=...)``
     (DESIGN.md §13); without it, fusion requests fall back to the
     dense-only result.
+
+    Host spans (DESIGN.md §9): ``hi2.build`` over ``hi2.build.kmeans``
+    (when it trains the centres), ``hi2.build.cluster_lists``,
+    ``hi2.build.term_lists`` (the BM25 fit and the lists) and
+    ``hi2.build.codec``.
     """
     codec_impl = codecs.get(codec)    # fail fast on unknown specs
     if sparse and not use_terms:
@@ -162,45 +169,52 @@ def build(key: Array,
     elif doc_assign is None:
         doc_assign = cs_mod.select_for_doc(cluster_sel, doc_embeddings)
 
-    if use_clusters:
-        _, assign_scores = cs_mod.doc_scores(cluster_sel, doc_embeddings,
-                                             doc_assign)
-        cluster_lists = il.build(np.arange(n_docs), np.asarray(doc_assign),
-                                 np.asarray(assign_scores),
-                                 n_lists=n_clusters,
-                                 capacity=cluster_capacity)
-    else:
-        cluster_lists = il.PaddedLists(
-            entries=jnp.full((n_clusters, 1), PAD_DOC, jnp.int32),
-            lengths=jnp.zeros((n_clusters,), jnp.int32))
+    with spans.span("hi2.build.cluster_lists"):
+        if use_clusters:
+            _, assign_scores = cs_mod.doc_scores(cluster_sel,
+                                                 doc_embeddings, doc_assign)
+            cluster_lists = il.build(np.arange(n_docs),
+                                     np.asarray(doc_assign),
+                                     np.asarray(assign_scores),
+                                     n_lists=n_clusters,
+                                     capacity=cluster_capacity)
+        else:
+            cluster_lists = il.PaddedLists(
+                entries=jnp.full((n_clusters, 1), PAD_DOC, jnp.int32),
+                lengths=jnp.zeros((n_clusters,), jnp.int32))
 
     # --- term side --------------------------------------------------------
-    if term_sel is None or term_pos_scores is None:
-        term_sel, term_pos_scores, _ = ts_mod.fit_unsup(doc_tokens, vocab_size)
-
-    sparse_weights = None
-    if use_terms:
-        term_ids, term_scores = ts_mod.doc_terms(doc_tokens, term_pos_scores,
-                                                 k1_terms)
-        doc_rep = np.repeat(np.arange(n_docs), k1_terms)
-        if sparse:
-            term_lists, sparse_weights = il.build_scored(
-                doc_rep, np.asarray(term_ids).reshape(-1),
-                np.asarray(term_scores).reshape(-1),
-                n_lists=vocab_size, capacity=term_capacity)
+    with spans.span("hi2.build.term_lists"):
+        if term_sel is None or term_pos_scores is None:
+            term_sel, term_pos_scores, _ = ts_mod.fit_unsup(doc_tokens,
+                                                            vocab_size)
+        sparse_weights = None
+        if use_terms:
+            term_ids, term_scores = ts_mod.doc_terms(
+                doc_tokens, term_pos_scores, k1_terms)
+            doc_rep = np.repeat(np.arange(n_docs), k1_terms)
+            if sparse:
+                term_lists, sparse_weights = il.build_scored(
+                    doc_rep, np.asarray(term_ids).reshape(-1),
+                    np.asarray(term_scores).reshape(-1),
+                    n_lists=vocab_size, capacity=term_capacity)
+            else:
+                term_lists = il.build(
+                    doc_rep, np.asarray(term_ids).reshape(-1),
+                    np.asarray(term_scores).reshape(-1),
+                    n_lists=vocab_size, capacity=term_capacity)
         else:
-            term_lists = il.build(doc_rep, np.asarray(term_ids).reshape(-1),
-                                  np.asarray(term_scores).reshape(-1),
-                                  n_lists=vocab_size, capacity=term_capacity)
-    else:
-        term_lists = il.PaddedLists(
-            entries=jnp.full((vocab_size, 1), PAD_DOC, jnp.int32),
-            lengths=jnp.zeros((vocab_size,), jnp.int32))
+            term_lists = il.PaddedLists(
+                entries=jnp.full((vocab_size, 1), PAD_DOC, jnp.int32),
+                lengths=jnp.zeros((vocab_size,), jnp.int32))
 
     # --- codec ------------------------------------------------------------
-    codec_params = codec_impl.train(k_codec, doc_embeddings,
-                                    pq_m=pq_m, pq_k=pq_k)
-    doc_planes = codec_impl.encode(codec_params, doc_embeddings)
+    with spans.span("hi2.build.codec"):
+        codec_params = codec_impl.train(k_codec, doc_embeddings,
+                                        pq_m=pq_m, pq_k=pq_k)
+        doc_planes = codec_impl.encode(codec_params, doc_embeddings)
+        if spans.recording():       # the span ends where the work does
+            jax.block_until_ready((codec_params, doc_planes))
 
     return HybridIndex(cluster_sel=cluster_sel, term_sel=term_sel,
                        cluster_lists=cluster_lists, term_lists=term_lists,

@@ -139,6 +139,7 @@ def topk_scores(x: jax.Array, emb: jax.Array, *, k: int, n_blk: int = 256,
             jax.ShapeDtypeStruct((n, k), jnp.int32),
         ],
         interpret=interpret,
+        name="topk_scores",
     )(x, emb)
 
 
@@ -173,5 +174,6 @@ def assign_argmax(x: jax.Array, centroids: jax.Array, *, n_blk: int = 256,
             jax.ShapeDtypeStruct((n, 1), jnp.int32),
         ],
         interpret=interpret,
+        name="assign_argmax",
     )(x, centroids)
     return s[:, 0], i[:, 0]

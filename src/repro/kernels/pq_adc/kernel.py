@@ -77,6 +77,7 @@ def pq_adc_fragmajor(lut: jax.Array, codes_fm: jax.Array, *,
         out_specs=pl.BlockSpec((1, 1, c_blk), lambda bi, ci: (bi, 0, ci)),
         out_shape=jax.ShapeDtypeStruct((b, 1, c), jnp.float32),
         interpret=interpret,
+        name="pq_adc_fragmajor",
     )(jnp.swapaxes(lut, 1, 2), codes_fm)
     return out.reshape(b, c)
 
@@ -150,6 +151,7 @@ def pq_adc_fused(lut: jax.Array, codes_plane: jax.Array, ids: jax.Array,
         scratch_shapes=row_gather.scratch_shapes(
             c_blk, codes_plane.shape[1], codes_plane.dtype),
         interpret=interpret,
+        name="pq_adc_fused",
     )(ids.reshape(b * n_blk, 1, c_blk), jnp.swapaxes(lut, 1, 2),
       live.reshape(b, 1, c), codes_plane)
     return out.reshape(b, c)

@@ -69,6 +69,7 @@ def sq8_dot_fused(q_scaled: jax.Array, codes_plane: jax.Array,
         scratch_shapes=row_gather.scratch_shapes(c_blk, w,
                                                  codes_plane.dtype),
         interpret=interpret,
+        name="sq8_dot_fused",
     )(ids.reshape(b * n_blk, 1, c_blk), q_scaled.reshape(b, 1, w),
       live.reshape(b, 1, c), codes_plane)
     return out.reshape(b, c)

@@ -73,6 +73,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import spans
 from repro.checkpoint import checkpoint as ckpt
 from repro.core import codecs
 from repro.core import exec as qexec
@@ -264,15 +265,25 @@ class Server:
 
     def query(self, query_emb: np.ndarray, query_tokens: np.ndarray,
               namespaces=None) -> hi.SearchResult:
-        n, qe, qt = self._pad(query_emb, query_tokens)
-        res = self._search(self.index, qe, qt,
-                           filter=self._filter(namespaces, n))
-        self.n_served += n
-        return hi.SearchResult(
-            doc_ids=res.doc_ids[:n],
-            scores=res.scores[:n],
-            n_candidates=res.n_candidates[:n],
-            partial=bool(np.asarray(getattr(res, "partial", False))))
+        """One padded batch through the search program.  Host spans
+        (DESIGN.md §9): ``hi2.query`` (``id`` = queries served before
+        this call) over ``hi2.query.pad``, ``hi2.query.search`` (the
+        program's enqueue) and ``hi2.query.split`` (the call's rows; its
+        read of the ``partial`` flag waits for the program to finish)."""
+        with spans.span("hi2.query", id=self.n_served):
+            with spans.span("hi2.query.pad"):
+                n, qe, qt = self._pad(query_emb, query_tokens)
+                ns = self._filter(namespaces, n)
+            with spans.span("hi2.query.search"):
+                res = self._search(self.index, qe, qt, filter=ns)
+            self.n_served += n
+            with spans.span("hi2.query.split"):
+                return hi.SearchResult(
+                    doc_ids=res.doc_ids[:n],
+                    scores=res.scores[:n],
+                    n_candidates=res.n_candidates[:n],
+                    partial=bool(np.asarray(getattr(res, "partial",
+                                                    False))))
 
     # mutation API — live only on the mutable servers below
     def add(self, doc_emb: np.ndarray, doc_tokens: np.ndarray,

@@ -5,7 +5,8 @@ TPU compiler's rules: block-shape tiling, memory layouts, VMEM and HBM
 limits.  These tests lower and compile the search path's kernels and
 the k-means assignment kernel at the ``hi2-synth/serve_msmarco`` widths
 (``configs/hi2_synth.py``) for one v5e chip, plus the XLA serving step
-at the batch the chip's HBM holds.
+at the batch the chip's HBM holds, and the names and stages of the
+fused kernels in the compiled program.
 Nothing runs, so they say nothing about results or speed.
 
 The topology is described inside a module-scoped fixture — never at
@@ -15,6 +16,7 @@ them loads it.  The persistent compilation cache is off around them (a
 compile for a described chip can be written to it but never read back).
 """
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -124,3 +126,68 @@ def test_xla_search_step_fits_one_chip(one_chip):
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
     assert used < 15.75e9, f"{used / 1e9:.2f} GB exceeds one v5e's HBM"
+
+
+def _custom_calls(text):
+    """(instruction name, op_name) of each Pallas call of a program."""
+    out = []
+    for line in text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' in line:
+            name = re.match(r"\s*(?:ROOT\s+)?%?([\w.\-]+)", line).group(1)
+            op = re.search(r'op_name="([^"]*)"', line)
+            out.append((name, op.group(1) if op else ""))
+    return out
+
+
+def test_fused_kernels_keep_their_names(one_chip):
+    """Each fused Pallas call is named explicitly: the name of its
+    instruction in the program and in a profiler trace."""
+    h, m, k = SHAPE.hidden, SHAPE.pq_m, SHAPE.pq_k
+    n, c = 4096, 1024
+    cases = [
+        ("sq8_dot_fused", lambda *a: sq8_kernel.sq8_dot_fused(
+            *a, interpret=False),
+         [(B, h), (n, h), (B, c), (B, c)],
+         [jnp.float32, jnp.uint8, jnp.int32, jnp.int32]),
+        ("pq_adc_fused", lambda *a: adc_kernel.pq_adc_fused(
+            *a, interpret=False),
+         [(B, m, k), (n, 128), (B, c), (B, c)],
+         [jnp.float32, jnp.uint8, jnp.int32, jnp.int32]),
+        ("topk_scores", lambda x, e: at_kernel.topk_scores(
+            x, e, k=SHAPE.kc, n_blk=B, l_blk=512, l_true=1000,
+            interpret=False),
+         [(B, h), (1024, h)], [jnp.float32, jnp.float32]),
+    ]
+    for kernel, fn, shapes, dtypes in cases:
+        _, text = _compile(fn, *(_sds(one_chip, s, d)
+                                 for s, d in zip(shapes, dtypes)))
+        names = [name for name, _ in _custom_calls(text)]
+        assert names and all(nm.startswith(kernel + ".") or nm == kernel
+                             for nm in names), (kernel, names)
+
+
+def test_sq8_search_kernels_lie_in_their_stages(one_chip, monkeypatch):
+    """The served sq8 + refine step with the fused path: the scoring
+    kernel is under ``hi2.score``, the dispatch top-k under
+    ``hi2.dispatch``."""
+    from repro.kernels.assign_topk import ops as at_ops
+    from repro.kernels.sq8_dot import ops as sq8_ops
+
+    for mod in (at_ops, sq8_ops):
+        monkeypatch.setattr(mod, "interpret_mode", lambda: False)
+    shape = dataclasses.replace(SHAPE, n_docs=1 << 14, codec="refine:sq8:4")
+    index = jax.tree.map(lambda s: _sds(one_chip, s.shape, s.dtype),
+                         cells._hi2_abstract_index(shape))
+    jax.clear_caches()       # no trace made with the interpreter is reused
+    try:
+        _, text = _compile(
+            lambda idx, qe, qt: hi.search(idx, qe, qt, kc=shape.kc,
+                                          k2=shape.k2, top_r=shape.top_r,
+                                          use_kernel=True),
+            index, _sds(one_chip, (B, shape.hidden), jnp.float32),
+            _sds(one_chip, (B, shape.query_len), jnp.int32))
+    finally:
+        jax.clear_caches()
+    stage = {name.rsplit(".", 1)[0]: re.findall(r"hi2\.(\w+)", op)
+             for name, op in _custom_calls(text)}
+    assert stage == {"sq8_dot_fused": ["score"], "topk_scores": ["dispatch"]}
