@@ -29,7 +29,6 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import row_gather
 
@@ -96,9 +95,11 @@ def pq_adc_fragmajor(lut: jax.Array, codes_fm: jax.Array, *,
 #
 #   · each grid step's c_blk candidate ids arrive in SMEM, so every
 #     row's HBM address is known to the scalar core;
-#   · the codes plane stays in HBM (memory_space=ANY); each candidate's
-#     8-row tile group is DMA'd into VMEM, double-buffered, and its row
-#     extracted into a (c_blk, w) int32 tile;
+#   · the codes plane stays in HBM (memory_space=ANY); each live
+#     candidate's 8-row tile group is DMA'd into VMEM, double-buffered,
+#     and its row extracted into a (c_blk, w) int32 tile; dead slots
+#     are not gathered, and a block with no live slot is neither
+#     gathered nor scored;
 #   · the live mask (dedup ∧ ¬tombstone ∧ namespace) is applied
 #     in-kernel: masked lanes leave as -inf, so the (B, C) score plane
 #     that reaches HBM is already selection-ready.
@@ -110,13 +111,13 @@ def pq_adc_fragmajor(lut: jax.Array, codes_fm: jax.Array, *,
 # oracle's m-reduction order differs (DESIGN.md §11 bounds it).
 
 
-def _adc_fused_kernel(ids_ref, lut_ref, live_ref, plane_ref, out_ref,
-                      groups_sc, rows_sc, sems, *, m: int, k: int,
-                      c_blk: int):
-    row_gather.gather_rows(ids_ref, plane_ref, groups_sc, rows_sc, sems,
-                           c_blk)
-    acc = _adc_accumulate(rows_sc[...].T, lut_ref[0], m, k, c_blk)
-    out_ref[0] = jnp.where(live_ref[0] != 0, acc, -jnp.inf)
+def _adc_fused_kernel(ids_ref, count_ref, lut_ref, live_ref, plane_ref,
+                      out_ref, *scratch, m: int, k: int, c_blk: int):
+    def score_rows(rows):                              # (c_blk, w) i32
+        return _adc_accumulate(rows.T, lut_ref[0], m, k, c_blk)
+
+    row_gather.masked_scores(score_rows, ids_ref, count_ref, live_ref,
+                             plane_ref, out_ref, *scratch, c_blk=c_blk)
 
 
 @functools.partial(jax.jit, static_argnames=("c_blk", "interpret"))
@@ -124,7 +125,8 @@ def pq_adc_fused(lut: jax.Array, codes_plane: jax.Array, ids: jax.Array,
                  live: jax.Array, *, c_blk: int = 256,
                  interpret: bool = False) -> jax.Array:
     """lut: (B, m, k) f32; codes_plane: (N, w) int, N % 8 == 0,
-    w % 128 == 0, w ≥ m; ids: (B, C) i32 in [0, N); live: (B, C) i32
+    w % 128 == 0, w ≥ m; ids: (B, C) i32 in [0, N) on live slots,
+    ``row_gather.DEAD`` (never gathered) on dead ones; live: (B, C) i32
     (0 = masked) → scores (B, C) f32, ``-inf`` on masked lanes.
 
     C must be a multiple of ``c_blk`` and k of 128 (ops.py pads all of
@@ -139,9 +141,8 @@ def pq_adc_fused(lut: jax.Array, codes_plane: jax.Array, ids: jax.Array,
         functools.partial(_adc_fused_kernel, m=m, k=k, c_blk=c_blk),
         grid=(b, n_blk),
         in_specs=[
-            pl.BlockSpec((1, 1, c_blk),
-                         lambda bi, ci: (bi * n_blk + ci, 0, 0),
-                         memory_space=pltpu.SMEM),
+            row_gather.ids_spec(c_blk, n_blk),
+            row_gather.count_spec(n_blk),
             pl.BlockSpec((1, k, m), lambda bi, ci: (bi, 0, 0)),
             pl.BlockSpec((1, 1, c_blk), lambda bi, ci: (bi, 0, ci)),
             pl.BlockSpec(memory_space=pl.ANY),         # resident plane
@@ -152,6 +153,6 @@ def pq_adc_fused(lut: jax.Array, codes_plane: jax.Array, ids: jax.Array,
             c_blk, codes_plane.shape[1], codes_plane.dtype),
         interpret=interpret,
         name="pq_adc_fused",
-    )(ids.reshape(b * n_blk, 1, c_blk), jnp.swapaxes(lut, 1, 2),
-      live.reshape(b, 1, c), codes_plane)
+    )(ids.reshape(b * n_blk, 1, c_blk), row_gather.block_counts(live, c_blk),
+      jnp.swapaxes(lut, 1, 2), live.reshape(b, 1, c), codes_plane)
     return out.reshape(b, c)

@@ -46,8 +46,10 @@ def pq_adc_fused(lut: jax.Array, codes_plane: jax.Array, ids: jax.Array,
     (B, C, m) intermediate is ever allocated.
 
     Padding done here so the kernel sees aligned shapes only:
-      · C → multiple of ``c_blk`` with ids=0 / live=0 (rows stripped
-        after the call; id 0 keeps the in-kernel DMA in bounds);
+      · ids of dead slots → ``row_gather.DEAD``, which the kernel
+        never gathers; live ids clipped into ``[0, N)``;
+      · C → multiple of ``c_blk`` with dead slots (stripped after the
+        call);
       · k → multiple of 128 with zero LUT columns (codes < k never
         select them);
       · the plane → whole (8, 128) tiles (:func:`row_gather.pad_plane`;
@@ -62,10 +64,11 @@ def pq_adc_fused(lut: jax.Array, codes_plane: jax.Array, ids: jax.Array,
     if k_pad:
         lut = jnp.pad(lut, ((0, 0), (0, 0), (0, k_pad)))
     c_pad = (-c) % c_blk
-    ids = jnp.clip(ids.astype(jnp.int32), 0, codes_plane.shape[0] - 1)
+    ids = row_gather.live_ids(ids, live, codes_plane.shape[0])
     live = live.astype(jnp.int32)
     if c_pad:
-        ids = jnp.pad(ids, ((0, 0), (0, c_pad)))
+        ids = jnp.pad(ids, ((0, 0), (0, c_pad)),
+                      constant_values=row_gather.DEAD)
         live = jnp.pad(live, ((0, 0), (0, c_pad)))
     out = kernel.pq_adc_fused(lut, row_gather.pad_plane(codes_plane), ids,
                               live, c_blk=c_blk,

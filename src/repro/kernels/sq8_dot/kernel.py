@@ -1,12 +1,13 @@
-"""Fused gather + dequantized dot for the SQ8 codec (DESIGN.md §7, §11).
+"""Fused gather + dequantized dot for the SQ8 codec (DESIGN.md §11).
 
 SQ8 scoring is ⟨q·scale, code⟩ + ⟨q, lo⟩: a pre-scaled dot over the
 gathered byte rows plus a per-query bias.  The unfused path gathers the
 (B, C, h) byte rows in HBM first; this kernel keeps the (N, h) codes
-plane resident in HBM and DMAs candidate rows straight into VMEM —
-the shared :mod:`repro.kernels.row_gather` as in
+plane resident in HBM and DMAs the rows of live candidates straight
+into VMEM — the shared :mod:`repro.kernels.row_gather` as in
 ``pq_adc/kernel._adc_fused_kernel``, with the one-hot ADC loop replaced
-by a single (1, h)·(c_blk, h)ᵀ MXU dot at full f32 precision.
+by a single (1, h)·(c_blk, h)ᵀ MXU dot at full f32 precision.  A block
+with no live candidate is neither gathered nor scored.
 
 The live mask is applied in-kernel (-inf); the per-query bias is added
 *outside* by the caller after masking (-inf + bias = -inf, so masked
@@ -20,22 +21,21 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import row_gather
 
 
-def _sq8_fused_kernel(ids_ref, q_ref, live_ref, plane_ref, out_ref,
-                      groups_sc, rows_sc, sems, *, c_blk: int):
-    row_gather.gather_rows(ids_ref, plane_ref, groups_sc, rows_sc, sems,
-                           c_blk)
-    q = q_ref[0]                                       # (1, h) f32, pre-scaled
-    rows = rows_sc[...].astype(jnp.float32)            # (c_blk, h)
-    acc = jax.lax.dot_general(                         # (1, c_blk)
-        q, rows, (((1,), (1,)), ((), ())),
-        precision=jax.lax.Precision.HIGHEST,
-        preferred_element_type=jnp.float32)
-    out_ref[0] = jnp.where(live_ref[0] != 0, acc, -jnp.inf)
+def _sq8_fused_kernel(ids_ref, count_ref, q_ref, live_ref, plane_ref,
+                      out_ref, *scratch, c_blk: int):
+    def score_rows(rows):                              # (c_blk, h) i32
+        return jax.lax.dot_general(                    # (1, c_blk)
+            q_ref[0], rows.astype(jnp.float32),        # q pre-scaled f32
+            (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)
+
+    row_gather.masked_scores(score_rows, ids_ref, count_ref, live_ref,
+                             plane_ref, out_ref, *scratch, c_blk=c_blk)
 
 
 @functools.partial(jax.jit, static_argnames=("c_blk", "interpret"))
@@ -44,7 +44,8 @@ def sq8_dot_fused(q_scaled: jax.Array, codes_plane: jax.Array,
                   interpret: bool = False) -> jax.Array:
     """q_scaled: (B, h) f32; codes_plane: (N, w) u8, N % 8 == 0,
     w % 128 == 0, w ≥ h (zero-padded lanes); ids: (B, C) i32 in
-    [0, N); live: (B, C) i32 → (B, C) f32 bias-free scores, ``-inf`` on
+    [0, N) on live slots, ``row_gather.DEAD`` (never gathered) on dead
+    ones; live: (B, C) i32 → (B, C) f32 bias-free scores, ``-inf`` on
     masked lanes.  C must be a multiple of ``c_blk`` (ops.py pads)."""
     b, h = q_scaled.shape
     _, c = ids.shape
@@ -57,9 +58,8 @@ def sq8_dot_fused(q_scaled: jax.Array, codes_plane: jax.Array,
         functools.partial(_sq8_fused_kernel, c_blk=c_blk),
         grid=(b, n_blk),
         in_specs=[
-            pl.BlockSpec((1, 1, c_blk),
-                         lambda bi, ci: (bi * n_blk + ci, 0, 0),
-                         memory_space=pltpu.SMEM),
+            row_gather.ids_spec(c_blk, n_blk),
+            row_gather.count_spec(n_blk),
             pl.BlockSpec((1, 1, w), lambda bi, ci: (bi, 0, 0)),
             pl.BlockSpec((1, 1, c_blk), lambda bi, ci: (bi, 0, ci)),
             pl.BlockSpec(memory_space=pl.ANY),         # resident plane
@@ -70,6 +70,6 @@ def sq8_dot_fused(q_scaled: jax.Array, codes_plane: jax.Array,
                                                  codes_plane.dtype),
         interpret=interpret,
         name="sq8_dot_fused",
-    )(ids.reshape(b * n_blk, 1, c_blk), q_scaled.reshape(b, 1, w),
-      live.reshape(b, 1, c), codes_plane)
+    )(ids.reshape(b * n_blk, 1, c_blk), row_gather.block_counts(live, c_blk),
+      q_scaled.reshape(b, 1, w), live.reshape(b, 1, c), codes_plane)
     return out.reshape(b, c)

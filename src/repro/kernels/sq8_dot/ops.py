@@ -1,6 +1,7 @@
 """Public jit'd wrapper for the fused SQ8 gather+dot kernel: pads C to
-the tile size and the plane to whole tiles, clips ids defensively, and
-takes the compile-or-interpret decision of
+the tile size and the plane to whole tiles, clips live ids defensively
+and marks dead ones (:func:`row_gather.live_ids`), and takes the
+compile-or-interpret decision of
 :func:`repro.kernels.interpret_mode` so CPU CI runs the same kernel
 body."""
 from __future__ import annotations
@@ -29,10 +30,11 @@ def sq8_dot_fused(q_scaled: jax.Array, codes_plane: jax.Array,
         return ref.sq8_dot_fused(q_scaled, codes_plane, ids, live)
     _, c = ids.shape
     c_pad = (-c) % c_blk
-    ids = jnp.clip(ids.astype(jnp.int32), 0, codes_plane.shape[0] - 1)
+    ids = row_gather.live_ids(ids, live, codes_plane.shape[0])
     live = live.astype(jnp.int32)
     if c_pad:
-        ids = jnp.pad(ids, ((0, 0), (0, c_pad)))
+        ids = jnp.pad(ids, ((0, 0), (0, c_pad)),
+                      constant_values=row_gather.DEAD)
         live = jnp.pad(live, ((0, 0), (0, c_pad)))
     out = kernel.sq8_dot_fused(q_scaled, row_gather.pad_plane(codes_plane),
                                ids, live, c_blk=c_blk,

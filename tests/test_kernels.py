@@ -3,6 +3,7 @@ allclose against the pure-jnp oracles, in interpret mode on CPU."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.kernels.assign_topk import ops as at_ops, ref as at_ref
@@ -167,6 +168,94 @@ def test_sq8_dot_fused_matches_oracle(b, c, h, mask_row):
     np.testing.assert_array_equal(np.isinf(got), np.isinf(want))
     fin = np.isfinite(want)
     np.testing.assert_allclose(got[fin], want[fin], rtol=1e-4, atol=1e-2)
+
+
+# --------------------------------------------------------------------------
+# fused scorers over block-structured live masks: dead blocks are not
+# scored and dead slots not gathered (kernels/row_gather.py)
+# --------------------------------------------------------------------------
+
+#: 2 queries × 4 blocks of 128 slots, the last one padded; "lists" of
+#: capacity 256 filled from the front, as the served candidate plane is
+SKIP_B, SKIP_C, SKIP_BLK, SKIP_LIST, SKIP_N = 2, 500, 128, 256, 400
+#: the row every dead slot points at in the "dead_ids_poisoned" case
+POISON = SKIP_N - 1
+SKIP_CASES = ["all_dead", "list_prefix", "one_slot_per_block",
+              "dead_ids_poisoned", "all_live"]
+
+
+def _skip_case(case):
+    """(ids, live) of one case: live ids never name :data:`POISON`."""
+    rng = np.random.default_rng(SKIP_CASES.index(case))
+    live = np.zeros((SKIP_B, SKIP_C), np.int32)
+    if case == "list_prefix":          # live prefix, dead blocks behind
+        for row, prefixes in enumerate([(37, 200), (128, 0)]):
+            for lst, n in enumerate(prefixes):
+                live[row, lst * SKIP_LIST:lst * SKIP_LIST + n] = 1
+    elif case == "one_slot_per_block":   # first, middle, last; one dead
+        for blk, pos in enumerate((0, SKIP_BLK // 2 - 1, SKIP_BLK - 1)):
+            live[:, blk * SKIP_BLK + pos] = 1
+    elif case == "dead_ids_poisoned":
+        live = (rng.random((SKIP_B, SKIP_C)) < 0.5).astype(np.int32)
+    elif case == "all_live":
+        live[:] = 1
+    ids = rng.integers(0, POISON, (SKIP_B, SKIP_C), dtype=np.int32)
+    if case == "dead_ids_poisoned":
+        ids = np.where(live != 0, ids, POISON).astype(np.int32)
+    return jnp.asarray(ids), jnp.asarray(live)
+
+
+def _assert_dead_lanes_are_inf(got, live):
+    live = np.asarray(live) != 0
+    np.testing.assert_array_equal(np.isneginf(got), ~live)
+    assert np.isfinite(got[live]).all()
+
+
+@pytest.mark.parametrize("case", SKIP_CASES)
+def test_sq8_dot_fused_skips_dead_slots_and_blocks(case):
+    """Dead lanes are exactly -inf; live lanes bitwise the unfused
+    path's (queries in 1/64 steps keep every sum exact in f32, so any
+    order of accumulation agrees) and within the oracle's tolerance."""
+    h = 64
+    rng = np.random.default_rng(7)
+    q = jnp.asarray(rng.integers(-64, 65, (SKIP_B, h)) / 64, jnp.float32)
+    plane = rng.integers(0, 256, (SKIP_N, h)).astype(np.uint8)
+    plane[POISON] = 255
+    plane = jnp.asarray(plane)
+    ids, live = _skip_case(case)
+    got = np.asarray(sq8_ops.sq8_dot_fused(q, plane, ids, live,
+                                           c_blk=SKIP_BLK))
+    _assert_dead_lanes_are_inf(got, live)
+    unfused = np.asarray(sq8_ops.sq8_dot_fused(q, plane, ids, live,
+                                               use_kernel=False))
+    np.testing.assert_array_equal(got, unfused)
+    want = np.asarray(sq8_ref.sq8_dot_fused(q, plane, ids, live))
+    fin = np.isfinite(want)
+    np.testing.assert_allclose(got[fin], want[fin], rtol=1e-4, atol=1e-2)
+
+
+@pytest.mark.parametrize("case", SKIP_CASES)
+def test_pq_adc_fused_skips_dead_slots_and_blocks(case):
+    """Dead lanes are exactly -inf; live lanes bitwise the unfused
+    ``pq_adc_fragmajor`` kernel's over the gathered codes and within the
+    oracle's tolerance."""
+    m, k = 8, 256
+    rng = np.random.default_rng(11)
+    lut = jnp.asarray(rng.standard_normal((SKIP_B, m, k)), jnp.float32)
+    plane = rng.integers(0, k, (SKIP_N, m)).astype(np.uint8)
+    plane[POISON] = np.argmax(np.asarray(lut)[0], axis=-1)
+    plane = jnp.asarray(plane)
+    ids, live = _skip_case(case)
+    got = np.asarray(adc_ops.pq_adc_fused(lut, plane, ids, live,
+                                          c_blk=SKIP_BLK))
+    _assert_dead_lanes_are_inf(got, live)
+    fragmajor = np.asarray(adc_ops.pq_adc(
+        lut, plane[ids].astype(jnp.int32), c_blk=SKIP_BLK))
+    lv = np.asarray(live) != 0
+    np.testing.assert_array_equal(got[lv], fragmajor[lv])
+    want = np.asarray(adc_ref.pq_adc_fused(lut, plane, ids, live))
+    fin = np.isfinite(want)
+    np.testing.assert_allclose(got[fin], want[fin], rtol=1e-4, atol=1e-4)
 
 
 # --------------------------------------------------------------------------
