@@ -32,6 +32,9 @@ from repro.core import kmeans
 from repro.core.codecs import base
 
 Array = jax.Array
+#: the precision of every matmul that trains or encodes the codes: float32
+#: in full (a TPU's default is one bfloat16 pass), whatever context calls
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 # --------------------------------------------------------------------------
@@ -77,27 +80,31 @@ def train_pq(key: Array, x: Array, m: int, k: int = 256,
     return PQCodebook(codewords=codewords)
 
 
-#: rows per block of :func:`pq_encode`'s (n, m, k) distance plane —
-#: 2^20 docs at m=96, k=256 would be 103 GB of f32 in one piece
+#: rows per block of the encoders' (n, m, k) distance plane — 2^20 docs
+#: at m=96, k=256 would be 103 GB of f32 in one piece
 ENCODE_BLOCK = 4096
+
+
+def _nearest_codes(codebook: PQCodebook, frags: Array) -> Array:
+    """Per fragment the codeword of highest <x_j, c> - ||c||²/2 (the
+    nearest one): (rows, m, dsub) -> (rows, m) int32.  An argmax over
+    8-dim fragments flips on near-ties, so the scores are float32 in
+    full (``HIGHEST``) whatever context calls."""
+    c = codebook.codewords.astype(jnp.float32)  # (m, k, dsub)
+    c_norm = 0.5 * jnp.sum(c * c, axis=-1)  # (m, k)
+    scores = jnp.einsum("nmd,mkd->nmk", frags.astype(jnp.float32), c,
+                        precision=HIGHEST) - c_norm
+    return jnp.argmax(scores, axis=-1).astype(jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("block",))
 def pq_encode(codebook: PQCodebook, x: Array, *,
               block: int = ENCODE_BLOCK) -> Array:
     """Quantize embeddings to codes. (n, h) -> (n, m) int32 (values < k),
-    ``block`` rows at a time."""
-    # distance argmin per subspace: argmax(<x, c> - ||c||²/2)
-    c = codebook.codewords.astype(jnp.float32)  # (m, k, dsub)
-    c_norm = 0.5 * jnp.sum(c * c, axis=-1)  # (m, k)
-
-    def one_block(xi):
-        frags = split_fragments(xi, codebook.m)  # (block, m, dsub)
-        scores = jnp.einsum("nmd,mkd->nmk", frags.astype(jnp.float32),
-                            c) - c_norm
-        return jnp.argmax(scores, axis=-1).astype(jnp.int32)
-
-    return kmeans.map_blocks(one_block, x, min(block, x.shape[0]))
+    ``block`` rows at a time, at float32 in full (:func:`_nearest_codes`)."""
+    return kmeans.map_blocks(
+        lambda xi: _nearest_codes(codebook, split_fragments(xi, codebook.m)),
+        x, min(block, x.shape[0]))
 
 
 @functools.partial(jax.jit, static_argnames=("block",))
@@ -126,7 +133,7 @@ def adc_lut(codebook: PQCodebook, queries: Array) -> Array:
     qf = split_fragments(queries, codebook.m)  # (B, m, dsub)
     return jnp.einsum("bmd,mkd->bmk", qf.astype(jnp.float32),
                       codebook.codewords.astype(jnp.float32),
-                      precision=jax.lax.Precision.HIGHEST)
+                      precision=HIGHEST)
 
 
 @jax.jit
@@ -213,21 +220,46 @@ def train_opq(key: Array, x: Array, m: int, k: int = 256,
     cb = None
     for it in range(n_outer):
         key, sub = jax.random.split(key)
-        xr = x @ r
+        xr = jnp.matmul(x, r, precision=HIGHEST)
         cb = train_pq(sub, xr, m=m, k=k, n_iters=n_kmeans_iters)
         # Procrustes: min_R ||X R - X̂||_F  s.t. RᵀR = I
         xhat = pq_decode(cb, pq_encode(cb, xr))
-        u, _, vt = jnp.linalg.svd(x.T @ xhat, full_matrices=False)
-        r = u @ vt
+        u, _, vt = jnp.linalg.svd(jnp.matmul(x.T, xhat, precision=HIGHEST),
+                                  full_matrices=False)
+        r = jnp.matmul(u, vt, precision=HIGHEST)
     # final codebook on the final rotation
     key, sub = jax.random.split(key)
-    cb = train_pq(sub, x @ r, m=m, k=k, n_iters=n_kmeans_iters)
+    cb = train_pq(sub, jnp.matmul(x, r, precision=HIGHEST), m=m, k=k,
+                  n_iters=n_kmeans_iters)
     return OPQCodebook(rotation=r, codebook=cb)
 
 
 @jax.jit
+def opq_encode_block(opq: OPQCodebook, xi: Array) -> Array:
+    """OPQ codes of one block of rows: (rows, h) -> (rows, m) int32, the
+    rotation and the nearest codewords both at float32 in full
+    (``HIGHEST``) whatever context calls."""
+    xr = jnp.matmul(xi.astype(jnp.float32), opq.rotation, precision=HIGHEST)
+    return _nearest_codes(opq.codebook, split_fragments(xr, opq.m))
+
+
 def opq_encode(opq: OPQCodebook, x: Array) -> Array:
-    return pq_encode(opq.codebook, x.astype(jnp.float32) @ opq.rotation)
+    """OPQ codes of embeddings: (n, h) -> (n, m) int32, one call of
+    :func:`opq_encode_block` per ``ENCODE_BLOCK`` rows (the last block
+    ends at row n and overlaps the one before).  The stored codes then do
+    not depend on the caller's default precision, and no rotated (n, h)
+    copy of the corpus exists.  The blocks are calls of their own, not
+    the steps of one looped program: on a TPU v5e a program that rotates
+    and scores block after block returned codes that were not the
+    nearest codeword (PERF.md §7, 1)."""
+    n = x.shape[0]
+    b = min(ENCODE_BLOCK, n)
+    parts = []
+    for i in range(0, n, b):
+        start = min(i, n - b)
+        xi = jax.lax.dynamic_slice_in_dim(x, start, b)
+        parts.append(opq_encode_block(opq, xi)[i - start:])
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
 
 
 @jax.jit
@@ -238,11 +270,11 @@ def opq_adc_lut(opq: OPQCodebook, queries: Array) -> Array:
     """
     return adc_lut(opq.codebook,
                    jnp.matmul(queries.astype(jnp.float32), opq.rotation,
-                              precision=jax.lax.Precision.HIGHEST))
+                              precision=HIGHEST))
 
 
 def opq_reconstruction_mse(opq: OPQCodebook, x: Array) -> Array:
-    xr = x.astype(jnp.float32) @ opq.rotation
+    xr = jnp.matmul(x.astype(jnp.float32), opq.rotation, precision=HIGHEST)
     return reconstruction_mse(opq.codebook, xr)
 
 
@@ -304,6 +336,13 @@ class PQCodec(base.Codec):
 
 
 class OPQCodec(PQCodec):
+    """OPQ codes (paper §3.2): ``m`` uint8 codes a document over the
+    rotated corpus.  Training's and encoding's matmuls state float32 in
+    full (``HIGHEST``), so the code plane is the nearest codeword at
+    every position however the caller sets the default precision: a
+    single bfloat16 pass flips near-ties of the argmax.  Encoding runs
+    one call per block of rows (:func:`opq_encode`)."""
+
     name = "opq"
 
     def train(self, key: Array, embeddings: Array, *, pq_m: int = 8,
@@ -318,7 +357,7 @@ class OPQCodec(PQCodec):
         # decode in rotated space, rotate back (R orthogonal: R⁻¹ = Rᵀ)
         xr = pq_decode(params.codebook,
                        doc_planes["codes"].astype(jnp.int32))
-        return xr @ params.rotation.T
+        return jnp.matmul(xr, params.rotation.T, precision=HIGHEST)
 
     def abstract(self, n_docs: int, hidden: int, *, pq_m: int = 8,
                  pq_k: int = 256):

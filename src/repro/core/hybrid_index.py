@@ -144,7 +144,7 @@ def build(key: Array,
     Host spans (DESIGN.md §9): ``hi2.build`` over ``hi2.build.kmeans``
     (when it trains the centres), ``hi2.build.cluster_lists``,
     ``hi2.build.term_lists`` (the BM25 fit and the lists) and
-    ``hi2.build.codec``.
+    ``hi2.build.codec`` › ``.codec.train``, ``.codec.encode``.
     """
     codec_impl = codecs.get(codec)    # fail fast on unknown specs
     if sparse and not use_terms:
@@ -209,12 +209,17 @@ def build(key: Array,
                 lengths=jnp.zeros((vocab_size,), jnp.int32))
 
     # --- codec ------------------------------------------------------------
+    # each span ends where its work does
     with spans.span("hi2.build.codec"):
-        codec_params = codec_impl.train(k_codec, doc_embeddings,
-                                        pq_m=pq_m, pq_k=pq_k)
-        doc_planes = codec_impl.encode(codec_params, doc_embeddings)
-        if spans.recording():       # the span ends where the work does
-            jax.block_until_ready((codec_params, doc_planes))
+        with spans.span("hi2.build.codec.train"):
+            codec_params = codec_impl.train(k_codec, doc_embeddings,
+                                            pq_m=pq_m, pq_k=pq_k)
+            if spans.recording():
+                jax.block_until_ready(codec_params)
+        with spans.span("hi2.build.codec.encode"):
+            doc_planes = codec_impl.encode(codec_params, doc_embeddings)
+            if spans.recording():
+                jax.block_until_ready(doc_planes)
 
     return HybridIndex(cluster_sel=cluster_sel, term_sel=term_sel,
                        cluster_lists=cluster_lists, term_lists=term_lists,

@@ -3,11 +3,15 @@
 Every registered codec goes through the same build → search path, so
 these tests pin the seam itself: registry resolution errors, per-codec
 encode/score round trips against the decode oracle, the sq8
-quantization-error bound, uint8/i32 code equivalence, the refine
-codec's "lossless when R′ covers the budget" guarantee, and
-codec-validated checkpointing.
+quantization-error bound, uint8/i32 code equivalence, the PQ/OPQ
+encoders' float32-in-full code plane whatever the caller's default
+precision, the refine codec's "lossless when R′ covers the budget"
+guarantee, and codec-validated checkpointing.
 """
 import dataclasses
+import re
+import sys
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -15,8 +19,13 @@ import numpy as np
 import pytest
 
 from repro.core import codecs, hybrid_index as hi
-from repro.core.codecs import base as codecs_base
+from repro.core import segments as seg
+from repro.core.codecs import base as codecs_base, pq
 from repro.data import synthetic
+
+ROOT = Path(__file__).resolve().parents[1]
+if str(ROOT) not in sys.path:
+    sys.path.insert(0, str(ROOT))
 
 KEY = jax.random.key(0)
 
@@ -126,6 +135,109 @@ def test_pq_codes_pack_to_uint8_iff_small_k(spec):
 
 # (uint8 vs i32 code *search* equivalence lives with the other §Perf
 # claims in tests/test_perf_impls.py)
+
+
+# --------------------------------------------------------------------------
+# PQ/OPQ encoding: float32 in full whatever the caller's default precision
+# --------------------------------------------------------------------------
+
+#: the paper's OPQ widths (§5.1): h, m, k
+H, M, K = 768, 96, 256
+#: three whole encode blocks and a ragged one
+N_RAGGED = 3 * pq.ENCODE_BLOCK + 5
+
+
+def _published_opq(rotate: bool, seed: int = 0) -> pq.OPQCodebook:
+    """A random orthogonal rotation (the identity for plain PQ) and
+    random codewords at the published widths."""
+    rng = np.random.default_rng(seed)
+    rot = (np.linalg.qr(rng.standard_normal((H, H)))[0] if rotate
+           else np.eye(H))
+    cw = rng.standard_normal((M, K, H // M)) / np.sqrt(H)
+    return pq.OPQCodebook(
+        rotation=jnp.asarray(rot, jnp.float32),
+        codebook=pq.PQCodebook(codewords=jnp.asarray(cw, jnp.float32)))
+
+
+_DOT = re.compile(r"stablehlo\.dot_general .*")
+_DIMS = re.compile(r"tensor<((?:\d+x)+)[a-z]")
+
+
+@pytest.mark.parametrize("context", ["bfloat16", "highest"])
+@pytest.mark.parametrize("encoder", ["opq", "pq"])
+def test_encoders_state_full_precision_per_block(encoder, context):
+    """Every product of the encoder is lowered at HIGHEST whatever the
+    context says, and none spans more than one block of rows."""
+    opq = _published_opq(rotate=True)
+    x = jax.ShapeDtypeStruct((N_RAGGED, H), jnp.float32)
+    fn = pq.opq_encode if encoder == "opq" else pq.pq_encode
+    arg = opq if encoder == "opq" else opq.codebook
+    with jax.default_matmul_precision(context):
+        text = jax.jit(fn).lower(arg, x).as_text()
+    dots = _DOT.findall(text)
+    assert dots
+    for line in dots:
+        assert "precision = [HIGHEST, HIGHEST]" in line, line
+        dims = [int(d) for t in _DIMS.findall(line)
+                for d in t.rstrip("x").split("x")]
+        assert max(dims) <= pq.ENCODE_BLOCK, line
+
+
+@pytest.fixture(scope="module")
+def ragged_opq():
+    """Seeded rows over a ragged last block, an OPQ codebook at the
+    published widths, the float64 nearest codeword at each position,
+    and the positions the benchmark's reference leaves open as
+    near-ties (``derive.opq_codes``; slow on the CPU, so made once)."""
+    from bench import derive
+
+    opq = _published_opq(rotate=True, seed=1)
+    x = np.random.default_rng(2).standard_normal((N_RAGGED, H)).astype(
+        np.float32) / np.float32(np.sqrt(H))
+    rot = np.asarray(opq.rotation)
+    cw = np.asarray(opq.codebook.codewords)
+    ref = derive.opq_codes(jnp.asarray(x), rot, cw, lambda ids: x[ids])
+    xr = (x.astype(np.float64) @ rot.astype(np.float64)).reshape(
+        N_RAGGED, M, H // M).transpose(1, 0, 2)
+    cw64 = cw.astype(np.float64)
+    s = xr @ cw64.transpose(0, 2, 1) - 0.5 * (cw64 ** 2).sum(-1)[:, None]
+    sure = np.ones((N_RAGGED, M), bool)
+    sure[ref.alt_doc, ref.alt_pos] = False
+    return opq, x, np.argmax(s, axis=-1).T, sure
+
+
+@pytest.mark.parametrize("encoder", ["opq", "pq"])
+def test_encoders_give_the_float64_nearest_codeword(ragged_opq, encoder):
+    """At the published widths, over a ragged last block, the codes are
+    the float64 nearest codeword at every position the reference does
+    not leave open; plain PQ encodes the rows rotated beforehand."""
+    opq, x, nearest, sure = ragged_opq
+    assert sure.mean() > 0.99
+    with jax.default_matmul_precision("bfloat16"):
+        if encoder == "opq":
+            codes = pq.opq_encode(opq, jnp.asarray(x))
+        else:
+            xr = jnp.matmul(jnp.asarray(x), opq.rotation,
+                            precision=pq.HIGHEST)
+            codes = pq.pq_encode(opq.codebook, xr)
+    np.testing.assert_array_equal(np.asarray(codes)[sure], nearest[sure])
+
+
+def test_delta_inserts_store_the_base_encode():
+    """Docs streamed into the mutable index get the codes the base build
+    gave the same rows, whatever the precision each was called under."""
+    c = _corpus(n_docs=600)
+    with jax.default_matmul_precision("highest"):
+        mut = seg.MutableHybridIndex.create(
+            KEY, c.doc_emb, c.doc_tokens, c.vocab_size, delta_capacity=64,
+            n_clusters=16, k1_terms=4, codec="opq", pq_m=4, pq_k=64,
+            cluster_capacity=96, term_capacity=48, kmeans_iters=3)
+    rows = np.arange(7, 600, 13)
+    with jax.default_matmul_precision("bfloat16"):
+        mut.add_docs(c.doc_emb[rows], c.doc_tokens[rows])
+    delta = np.asarray(mut.delta_segment().doc_planes["codes"])
+    base = np.asarray(mut.base.doc_planes["codes"])
+    np.testing.assert_array_equal(delta[:len(rows)], base[rows])
 
 
 # --------------------------------------------------------------------------

@@ -238,7 +238,26 @@ def test_build_emits_its_phase_spans(corpus):
     names = [s.name for s in got]
     assert names == ["hi2.build", "hi2.build.kmeans",
                      "hi2.build.cluster_lists", "hi2.build.term_lists",
-                     "hi2.build.codec"]
-    assert got[0].parent is None and all(s.parent == 0 for s in got[1:])
-    inside = sum(s.end_ns - s.start_ns for s in got[1:])
+                     "hi2.build.codec", "hi2.build.codec.train",
+                     "hi2.build.codec.encode"]
+    assert got[0].parent is None and all(s.parent == 0 for s in got[1:5])
+    assert got[5].parent == got[6].parent == 4
+    inside = sum(s.end_ns - s.start_ns for s in got[1:5])
     assert inside <= got[0].end_ns - got[0].start_ns
+
+
+@pytest.mark.parametrize("codec", ["opq", "sq8"])
+def test_build_codec_spans_train_then_encode(corpus, codec):
+    c = corpus
+    spans.record()
+    hi.build(jax.random.key(0), jnp.asarray(c.doc_emb),
+             jnp.asarray(c.doc_tokens), c.vocab_size, codec=codec, **KW)
+    got = spans.take()
+    codec_at = [s.name for s in got].index("hi2.build.codec")
+    children = [s for s in got if s.parent == codec_at]
+    assert [s.name for s in children] == ["hi2.build.codec.train",
+                                          "hi2.build.codec.encode"]
+    train, encode = children
+    outer = got[codec_at]
+    assert outer.start_ns <= train.start_ns <= train.end_ns \
+        <= encode.start_ns <= encode.end_ns <= outer.end_ns

@@ -5,8 +5,9 @@ TPU compiler's rules: block-shape tiling, memory layouts, VMEM and HBM
 limits.  These tests lower and compile the search path's kernels and
 the k-means assignment kernel at the ``hi2-synth/serve_msmarco`` widths
 (``configs/hi2_synth.py``) for one v5e chip, plus the XLA serving step
-at the batch the chip's HBM holds, and the names and stages of the
-fused kernels in the compiled program.
+at the batch the chip's HBM holds, the build's OPQ encode of one chip's
+corpus, and the names and stages of the fused kernels in the compiled
+program.
 Nothing runs, so they say nothing about results or speed.
 
 The topology is described inside a module-scoped fixture — never at
@@ -25,6 +26,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs import hi2_synth
 from repro.core import hybrid_index as hi
+from repro.core.codecs import pq
 from repro.kernels.assign_topk import kernel as at_kernel
 from repro.kernels.pq_adc import kernel as adc_kernel
 from repro.kernels.sq8_dot import kernel as sq8_kernel
@@ -126,6 +128,29 @@ def test_xla_search_step_fits_one_chip(one_chip):
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
     assert used < 15.75e9, f"{used / 1e9:.2f} GB exceeds one v5e's HBM"
+
+
+def test_opq_encode_block_compiles_small_at_full_precision(one_chip):
+    """The program the build's OPQ encode calls once per block of rows
+    (2^20 docs take 256 calls), in the default context as the build
+    calls it: no rotated (n, h) f32 copy of the corpus (3.2 GB at 2^20
+    rows) among its temporaries, and every product at the highest
+    operand precision."""
+    h, m, k = SHAPE.hidden, SHAPE.pq_m, SHAPE.pq_k
+    opq = pq.OPQCodebook(
+        rotation=_sds(one_chip, (h, h), jnp.float32),
+        codebook=pq.PQCodebook(
+            codewords=_sds(one_chip, (m, k, h // m), jnp.float32)))
+    compiled, text = _compile(
+        pq.opq_encode_block, opq,
+        _sds(one_chip, (pq.ENCODE_BLOCK, h), jnp.float32))
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 1e9, f"{temp / 1e9:.2f} GB of temporaries"
+    dots = [line for line in text.splitlines()
+            if re.search(r"= \S+ (?:convolution|dot)\(", line)]
+    assert dots
+    for line in dots:
+        assert "operand_precision={highest,highest}" in line, line
 
 
 def _custom_calls(text):
