@@ -33,6 +33,7 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 Array = jax.Array
 
@@ -145,18 +146,34 @@ def dedup_mask(candidates: Array) -> Array:
     """First-occurrence mask over each row — TPU-friendly set semantics.
 
     Duplicates arise when a document sits in several dispatched lists
-    (cluster ∩ term hits). We sort ids, mark repeats, and scatter the
-    mask back — O(B·C log C), fixed shape, no hashing.
+    (cluster ∩ term hits).  Two sorts and no gather — O(B·C log C),
+    fixed shape, no hashing:
+
+    1. one stable sort of (ids, slot) keyed by id returns the sorted ids
+       *and* each one's slot, so the first occurrence in candidate order
+       leads its run of equal ids;
+    2. a slot is kept if its id differs from its left neighbour's and is
+       not PAD;
+    3. one sort of ``slot * 2 + keep`` carries that bit back to slot
+       order: ``slot`` is a permutation of ``0..C-1``, so the sorted
+       keys are ``2i + keep_i`` and ``& 1`` reads slot i's bit.
+
+    Both permuted values ride the sorts as payload: a ``take_along_axis``
+    (or a scatter) over C slots lowers on the TPU to a serialised
+    per-element gather that costs ~10× the sorts around it.
     """
     b, c = candidates.shape
-    order = jnp.argsort(candidates, axis=-1)
-    sorted_ids = jnp.take_along_axis(candidates, order, axis=-1)
+    if 2 * c > np.iinfo(np.int32).max:
+        raise ValueError(f"dedup_mask packs slot*2+bit into int32; C={c}")
+    slots = lax.broadcasted_iota(jnp.int32, (b, c), 1)
+    sorted_ids, order = lax.sort((candidates, slots), dimension=-1,
+                                 num_keys=1, is_stable=True)
     is_dup = jnp.concatenate(
         [jnp.zeros((b, 1), bool), sorted_ids[:, 1:] == sorted_ids[:, :-1]], axis=-1)
     keep_sorted = (~is_dup) & (sorted_ids != PAD_DOC)
-    # scatter back to original positions via the inverse permutation
-    inv = jnp.argsort(order, axis=-1)
-    return jnp.take_along_axis(keep_sorted, inv, axis=-1)
+    packed = lax.sort(order * 2 + keep_sorted.astype(jnp.int32), dimension=-1,
+                      is_stable=False)    # distinct keys: order-free
+    return (packed & 1).astype(bool)
 
 
 def list_size_histogram(lists: PaddedLists) -> np.ndarray:

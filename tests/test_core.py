@@ -2,6 +2,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.core import (bm25, cluster_selector as cs, inverted_lists as il,
@@ -179,6 +180,70 @@ def test_dedup_mask_keeps_exactly_first_occurrences():
     keep = np.asarray(il.dedup_mask(cands))[0]
     kept = np.asarray(cands)[0][keep]
     assert sorted(kept.tolist()) == [3, 5, 7]
+
+
+def _first_occurrence_mask(cands: np.ndarray) -> np.ndarray:
+    """numpy reference: True at the first slot of each non-PAD id."""
+    mask = np.zeros(cands.shape, bool)
+    for r, row in enumerate(cands):
+        _, first = np.unique(row, return_index=True)
+        mask[r, first] = True
+    return mask & (cands != il.PAD_DOC)
+
+
+def _served_plane(b: int = 4, c: int = 63_488) -> np.ndarray:
+    """A candidate plane at the served width: 62 lists of 1,024 slots,
+    each list a run of ids from a shared pool of 20,000 docs (heavy
+    cluster ∩ term duplication) followed by PAD to its capacity."""
+    rng = np.random.default_rng(16)
+    lists = rng.integers(0, 20_000, size=(b, c // 1024, 1024), dtype=np.int32)
+    lengths = rng.integers(0, 1025, size=(b, c // 1024, 1))
+    lists[np.arange(1024) >= lengths] = il.PAD_DOC
+    return lists.reshape(b, c)
+
+
+_DEDUP_CASES = {
+    "all_pad": np.full((2, 16), il.PAD_DOC, np.int32),
+    "all_duplicate": np.full((2, 16), 7, np.int32),
+    "c_is_1": np.array([[5], [il.PAD_DOC], [0]], np.int32),
+    "no_duplicates": np.stack([np.random.default_rng(r).permutation(64)
+                               for r in range(3)]).astype(np.int32),
+    "extreme_ids": np.array([[2**31 - 1, 0, il.PAD_DOC, 0, 2**31 - 1,
+                              il.PAD_DOC]], np.int32),
+    "mixed_small": np.random.default_rng(3).integers(
+        -1, 6, size=(5, 40)).astype(np.int32),
+    "served_width": _served_plane(),
+}
+
+
+@pytest.mark.parametrize("case", list(_DEDUP_CASES))
+def test_dedup_mask_matches_first_occurrence_slot_by_slot(case):
+    """Not just the set of kept ids: the kept slot of each id is its
+    first in candidate order (DESIGN.md §6/§9 bit-identity rests on it)."""
+    cands = _DEDUP_CASES[case]
+    keep = np.asarray(il.dedup_mask(jnp.asarray(cands)))
+    assert keep.dtype == bool and keep.shape == cands.shape
+    np.testing.assert_array_equal(keep, _first_occurrence_mask(cands))
+
+
+def _primitives(jaxpr) -> list[str]:
+    """Names of every primitive of a jaxpr, nested jaxprs included."""
+    names = []
+    for eqn in jaxpr.eqns:
+        names.append(eqn.primitive.name)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            names += _primitives(sub)
+    return names
+
+
+@pytest.mark.parametrize("shape", [(1, 1), (3, 40), (64, 63_488)])
+def test_dedup_mask_sorts_twice_and_never_gathers(shape):
+    """Both permuted values ride sort payloads: a gather over the C
+    slots costs ~10x the sorts on a TPU."""
+    prims = _primitives(jax.make_jaxpr(il.dedup_mask)(
+        jax.ShapeDtypeStruct(shape, jnp.int32)).jaxpr)
+    assert prims.count("sort") == 2, prims
+    assert not [p for p in prims if p.startswith(("gather", "scatter"))]
 
 
 def test_pruning_truncates_to_percentile():
